@@ -2,8 +2,8 @@
 
 Runs STANDALONE in its own process (``python -m benchmarks.bench_scaleout``)
 because ``--xla_force_host_platform_device_count`` must be set before jax
-initializes — ``benchmarks.run`` therefore shells out via :func:`run`
-instead of importing jax-side code from this module.
+initializes.  It is a simulated-CPU-mesh benchmark of modelled FLOPs; its
+counterpart on real chips is ``python chip_smoke.py --chips 4``.
 
 Reported per device count (1..N simulated host devices):
 
@@ -37,7 +37,6 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import subprocess
 import sys
 import time
 
@@ -46,35 +45,16 @@ BENCH_PATH = os.path.join(REPO_ROOT, "BENCH_scaleout.json")
 FORCE_FLAG = "--xla_force_host_platform_device_count"
 
 
-def run(fast: bool = True, device_counts=None):
-    """benchmarks.run entry point: re-exec this module in a fresh process
-    (the forced-host-device flag cannot take effect in a process that
-    already imported jax), then return the written artifact."""
-    cmd = [sys.executable, "-m", "benchmarks.bench_scaleout"]
-    if fast:
-        cmd.append("--smoke")
-    if device_counts:
-        cmd += ["--devices", ",".join(str(d) for d in device_counts)]
-    env = dict(os.environ)
-    env.setdefault("PYTHONPATH", os.path.join(REPO_ROOT, "src"))
-    subprocess.run(cmd, check=True, env=env, cwd=REPO_ROOT)
-    with open(BENCH_PATH) as f:
-        return json.load(f)
-
-
 # ---------------------------------------------------------------------------
-# everything below runs only in the re-exec'd process (jax imported lazily,
-# AFTER main() pins XLA_FLAGS)
+# jax is imported lazily, AFTER main() pins XLA_FLAGS
 # ---------------------------------------------------------------------------
 
 
 def _cost(compiled) -> dict:
-    """Per-device flops + collective bytes of a compiled executable (list-
-    or dict-shaped cost_analysis, depending on jax version)."""
+    """Per-device flops + collective bytes of a compiled executable."""
     from repro.launch.roofline import collective_bytes_from_hlo
 
-    ca = compiled.cost_analysis()
-    ca = ca[0] if isinstance(ca, list) else (ca or {})
+    ca = compiled.cost_analysis() or {}
     coll = collective_bytes_from_hlo(compiled.as_text())
     return {"flops_per_device": float(ca.get("flops") or 0.0),
             "coll_bytes_per_device": float(coll["per_device_bytes"])}
@@ -165,7 +145,7 @@ def _bench_grad_compress(ndev) -> dict:
     reduce payload)."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core import rgcn as rgcn_mod

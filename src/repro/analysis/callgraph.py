@@ -49,8 +49,7 @@ TRACERS = {
     "jax.lax.switch",
     "jax.lax.associative_scan",
     "jax.experimental.pallas.pallas_call",
-    "jax.experimental.shard_map.shard_map",
-    "jax.shard_map",  # the experimental alias graduated to the jax namespace
+    "jax.shard_map",
 }
 
 #: fully-qualified fids of kernel-package entry points that must ALWAYS be

@@ -378,7 +378,12 @@ def _sweep_core(x, pmask, init_idx, sil_mask, *, k_max: int, iters: int,
         labels_all = jax.lax.map(lloyd_one, cmask_all)  # (num_k, n_pad)
         onehot_all = (jax.nn.one_hot(labels_all, k_max, dtype=x.dtype)
                       * sil_mask[None, :, None])
-        sums_all = jax.lax.map(lambda oh: silhouette_sums(x, oh), onehot_all)
+        # the barrier keeps XLA from fusing the kernel into the stacking
+        # update of the map: the fused copy loses the kernel's scoped-VMEM
+        # limit and is refused on the TPU at 'highest' matmul precision
+        sums_all = jax.lax.map(
+            lambda oh: jax.lax.optimization_barrier(silhouette_sums(x, oh)),
+            onehot_all)
     else:
         def lloyd_one(cmask):
             def assign(cent):

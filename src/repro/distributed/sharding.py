@@ -108,6 +108,7 @@ def spec_for(
     for i, n in enumerate(names):
         if n == "batch" and shape[i] % bsize == 0:
             assign[i] = rules.batch_axes
+            break  # a PartitionSpec may use each mesh axis at most once
 
     # 2) tensor-parallel 'model' placement
     pref = rules.param_model_pref if is_param else rules.act_model_pref

@@ -20,6 +20,14 @@ from __future__ import annotations
 
 import jax
 
+#: scoped-VMEM budget for the kernels whose working set grows with the
+#: packed node count or the points bucket (rgcn_fused, silhouette_sums).
+#: The compiler's default scope is 16 MiB; at the main path's real sizes
+#: (4096 nodes, D=128 -> O=256; 4096 points x 256-d) these kernels need
+#: 18-30 MiB once f32 matmuls run at 'highest' precision, which splits
+#: each operand into bf16 parts.  A TPU v5e core has 128 MiB of VMEM.
+VMEM_LIMIT_BYTES = 64 * 2**20
+
 
 def default_interpret() -> bool:
     """Backend-aware interpret default for every Pallas wrapper: interpret

@@ -25,13 +25,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU scratch memory spaces (available in interpret mode too)
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -112,9 +106,9 @@ def flash_attention_fwd(q, k, v, *, scale, block_q=512, block_k=512,
         out_specs=o_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[
-            _VMEM((block_q, 128), jnp.float32),
-            _VMEM((block_q, 128), jnp.float32),
-            _VMEM((block_q, hd), jnp.float32),
+            pltpu.VMEM((block_q, 128), jnp.float32),
+            pltpu.VMEM((block_q, 128), jnp.float32),
+            pltpu.VMEM((block_q, hd), jnp.float32),
         ],
         interpret=interpret,
     )(q, k, v)

@@ -17,6 +17,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import VMEM_LIMIT_BYTES
 
 
 def _pad_rows(x, block_n):
@@ -35,8 +38,8 @@ def _kmeans_kernel(x_ref, c_ref, lab_ref, dist_ref):
         x, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )                                               # (bn, k)
     d = jnp.maximum(x2 - 2.0 * xc + c2[None, :], 0.0)
-    lab_ref[...] = jnp.argmin(d, axis=1).astype(jnp.int32)
-    dist_ref[...] = jnp.min(d, axis=1)
+    lab_ref[...] = jnp.argmin(d, axis=1, keepdims=True).astype(jnp.int32)
+    dist_ref[...] = jnp.min(d, axis=1, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
@@ -57,16 +60,16 @@ def kmeans_assign_fwd(x, cent, *, block_n=512, interpret=False):
             pl.BlockSpec((k, d), lambda i: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
+            pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
+            pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((np_,), jnp.int32),
-            jax.ShapeDtypeStruct((np_,), jnp.float32),
+            jax.ShapeDtypeStruct((np_, 1), jnp.int32),
+            jax.ShapeDtypeStruct((np_, 1), jnp.float32),
         ],
         interpret=interpret,
     )(x, cent)
-    return labels[:n], dists[:n]
+    return labels[:n, 0], dists[:n, 0]
 
 
 def _kmeans_fused_kernel(x_ref, c_ref, cm_ref, pm_ref,
@@ -81,21 +84,21 @@ def _kmeans_fused_kernel(x_ref, c_ref, cm_ref, pm_ref,
     i = pl.program_id(0)
     x = x_ref[...]                                  # (bn, d)
     c = c_ref[...]                                  # (k, d)
-    cmask = cm_ref[...]                             # (k,)   1 = live centroid
-    pmask = pm_ref[...]                             # (bn,)  1 = real point
+    cmask = cm_ref[...]                             # (1, k)  1 = live centroid
+    pmask = pm_ref[...]                             # (bn, 1) 1 = real point
     x2 = jnp.sum(x * x, axis=1, keepdims=True)
     c2 = jnp.sum(c * c, axis=1)
     xc = jax.lax.dot_general(
         x, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )
     d = jnp.maximum(x2 - 2.0 * xc + c2[None, :], 0.0)
-    d = jnp.where(cmask[None, :] > 0, d, jnp.inf)   # dead slots never win
-    lab = jnp.argmin(d, axis=1).astype(jnp.int32)
+    d = jnp.where(cmask > 0, d, jnp.inf)            # dead slots never win
+    lab = jnp.argmin(d, axis=1, keepdims=True).astype(jnp.int32)  # (bn, 1)
     lab_ref[...] = lab
-    dist_ref[...] = jnp.min(d, axis=1) * pmask      # padding adds 0 inertia
+    dist_ref[...] = jnp.min(d, axis=1, keepdims=True) * pmask  # pad: 0 inertia
     k = c.shape[0]
-    onehot = (lab[:, None] == jax.lax.broadcasted_iota(jnp.int32, (1, k), 1))
-    onehot = onehot.astype(jnp.float32) * pmask[:, None]
+    onehot = (lab == jax.lax.broadcasted_iota(jnp.int32, (1, k), 1))
+    onehot = onehot.astype(jnp.float32) * pmask
 
     @pl.when(i == 0)
     def _():
@@ -106,7 +109,7 @@ def _kmeans_fused_kernel(x_ref, c_ref, cm_ref, pm_ref,
         onehot, x, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )                                               # (k, d)
-    cnt_ref[...] += jnp.sum(onehot, axis=0)         # (k,)
+    cnt_ref[...] += jnp.sum(onehot, axis=0, keepdims=True)  # (1, k)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
@@ -116,8 +119,11 @@ def kmeans_assign_fused_fwd(x, cent, cmask, pmask, *, block_n=512,
     k = cent.shape[0]
     block_n = min(block_n, n)
     x = _pad_rows(x, block_n)
-    pmask = jnp.pad(pmask, (0, x.shape[0] - n))
     np_ = x.shape[0]
+    # 2-D (rows, 1) / (1, k) vector layouts: Mosaic and XLA agree on their
+    # tiling at every n, where a 1-D (block_n,) block is refused on TPU
+    pmask = jnp.pad(pmask, (0, np_ - n)).reshape(np_, 1)
+    cmask = cmask.reshape(1, k)
     grid = (np_ // block_n,)
     labels, dists, sums, cnts = pl.pallas_call(
         _kmeans_fused_kernel,
@@ -125,24 +131,24 @@ def kmeans_assign_fused_fwd(x, cent, cmask, pmask, *, block_n=512,
         in_specs=[
             pl.BlockSpec((block_n, d), lambda i: (i, 0)),
             pl.BlockSpec((k, d), lambda i: (0, 0)),
-            pl.BlockSpec((k,), lambda i: (0,)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
+            pl.BlockSpec((1, k), lambda i: (0, 0)),
+            pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
+            pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
+            pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
             pl.BlockSpec((k, d), lambda i: (0, 0)),
-            pl.BlockSpec((k,), lambda i: (0,)),
+            pl.BlockSpec((1, k), lambda i: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((np_,), jnp.int32),
-            jax.ShapeDtypeStruct((np_,), jnp.float32),
+            jax.ShapeDtypeStruct((np_, 1), jnp.int32),
+            jax.ShapeDtypeStruct((np_, 1), jnp.float32),
             jax.ShapeDtypeStruct((k, d), jnp.float32),
-            jax.ShapeDtypeStruct((k,), jnp.float32),
+            jax.ShapeDtypeStruct((1, k), jnp.float32),
         ],
         interpret=interpret,
     )(x, cent, cmask, pmask)
-    return labels[:n], dists[:n], sums, cnts
+    return labels[:n, 0], dists[:n, 0], sums, cnts[0]
 
 
 def _sil_sums_kernel(x_ref, xb_ref, oh_ref, sum_ref):
@@ -190,6 +196,8 @@ def silhouette_sums_fwd(x, onehot, *, block_n=512, interpret=False):
         ],
         out_specs=pl.BlockSpec((n, k), lambda j: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, k), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(x, xb, oh)
     return sums

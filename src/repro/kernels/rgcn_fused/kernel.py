@@ -32,6 +32,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import VMEM_LIMIT_BYTES
 
 
 def _rgcn_fused_flat_kernel(h_ref, src_ref, dst_ref, coef_ref, wnorm_ref,
@@ -55,14 +58,19 @@ def _rgcn_fused_flat_kernel(h_ref, src_ref, dst_ref, coef_ref, wnorm_ref,
     onehot_src = (iota_n == src[:, None]).astype(h.dtype)   # (be, P)
     onehot_dst = (iota_n == dst[:, None]).astype(jnp.float32)
 
+    # a one-hot gather of bf16 messages is exact at the MXU's native
+    # precision, the only one Mosaic accepts for bf16 operands (a global
+    # 'highest' would otherwise reach this dot and be refused)
     gathered = jax.lax.dot_general(                         # (be, D) via MXU
         onehot_src, h, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
+        precision=(jax.lax.Precision.DEFAULT if h.dtype == jnp.bfloat16
+                   else None),
     )
     D = h.shape[-1]
     weighted = (gathered[:, None, :] * w[:, :, None]).reshape(block_e, nb * D)
     msg = jax.lax.dot_general(                              # (be, O) via MXU
-        weighted, basis, (((1,), (0,)), ((), ())),
+        weighted, basis.astype(jnp.float32), (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
     scat = jax.lax.dot_general(                             # (P, O) via MXU
@@ -117,5 +125,7 @@ def rgcn_fused_flat_fwd(h, src, dst, coef, wnorm, basisflat, *, num_nodes,
         ],
         out_specs=pl.BlockSpec((P, O), lambda e: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((P, O), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(h, src2, dst2, coef, wnorm2, basisflat)

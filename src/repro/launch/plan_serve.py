@@ -22,6 +22,7 @@ from __future__ import annotations
 import argparse
 import json
 
+from repro.compile_cache import enable_compile_cache
 from repro.sampling.engine import bucket_key
 from repro.serving import (
     PlanService, parse_buckets, run_open_loop, synthetic_fleet,
@@ -49,6 +50,7 @@ def main(argv=None) -> int:
                     help="CI-sized run (fewer requests, one load)")
     ap.add_argument("--json", default=None, help="write results JSON here")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     n_requests = min(args.requests, 40) if args.smoke else args.requests
     loads = [float(x) for x in str(args.load).split(",") if x]
@@ -58,6 +60,7 @@ def main(argv=None) -> int:
     fleet = synthetic_fleet(n_requests, d=args.d, seed=args.seed)
     buckets = sorted({bucket_key(r.embeddings) for r in fleet})
     rows = []
+    failed = 0
     with PlanService(max_batch=args.max_batch,
                      max_delay_ms=args.max_delay_ms,
                      k_max=args.k_max, iters=args.iters,
@@ -79,12 +82,15 @@ def main(argv=None) -> int:
                 f"mean queue {s['mean_queue_depth']:.1f}, flushes "
                 f"{s['flush_causes']}", flush=True)
             rows.append(res.to_json())
+            failed += res.n_err
     if args.json:
         with open(args.json, "w") as f:
             json.dump({"buckets": [list(b) for b in buckets],
                        "loads": rows}, f, indent=1, sort_keys=True)
         print(f"[plan-serve] wrote {args.json}", flush=True)
-    return 0
+    if failed:
+        print(f"[plan-serve] {failed} request(s) FAILED", flush=True)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":

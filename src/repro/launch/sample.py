@@ -36,6 +36,7 @@ import math
 import os
 import time
 
+from repro.compile_cache import enable_compile_cache
 from repro.sampling import (
     ArtifactStore, available_methods, evaluate_metrics, get_method,
 )
@@ -373,6 +374,7 @@ def main(argv=None) -> int:
                     help="skip the on-disk packed-graph cache (always "
                          "re-trace; warm runs normally re-trace nothing)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     methods = (available_methods() if args.method == "all"
                else [m.strip() for m in args.method.split(",") if m.strip()])
@@ -423,7 +425,12 @@ def main(argv=None) -> int:
     _print_table(doc)
     print(f"\nresults JSON: {results_path} "
           f"({len(doc['results'])} rows, {doc['wall_time_s']:.0f}s)")
-    return 1 if doc["failures"] else 0
+    if doc["batch_plan_errors"]:
+        # the per-cell fallback served every plan, but the batched path the
+        # grid is meant to exercise failed: that is a failed run
+        print(f"{len(doc['batch_plan_errors'])} batched plan dispatch(es) "
+              f"FAILED and fell back to per-cell planning")
+    return 1 if doc["failures"] or doc["batch_plan_errors"] else 0
 
 
 if __name__ == "__main__":

@@ -26,7 +26,10 @@ Serving hooks (DESIGN.md §9; consumed by :mod:`repro.serving`):
   chunk, which ``plan_many`` uses to overlap host-side plan building with
   the next chunk's device dispatch;
 - ``errors="isolate"`` turns a poison request into an Exception entry in
-  the result list instead of killing the whole batch;
+  the result list instead of killing the whole batch; every program a
+  failed sweep dispatch re-runs through the sequential reference is
+  counted in ``stats["fallback_dispatches"]``, so a sweep that fails on
+  the device never passes for a served one;
 - ``record_timings`` stamps per-request dispatch telemetry into the plan
   ``extra`` so a server can account batch occupancy and service time.
 """
@@ -123,7 +126,7 @@ class PlanEngine:
     def _fresh_stats() -> dict:
         return {"programs": 0, "dispatches": 0, "errors": 0,
                 "warmed_executables": 0, "degraded_dispatches": 0,
-                "bucket_hist": []}
+                "fallback_dispatches": 0, "bucket_hist": []}
 
     def reset_stats(self) -> None:
         """Zero the INSTANCE counters (long-lived servers window their
@@ -228,7 +231,8 @@ class PlanEngine:
 
         ``errors="isolate"``: a failing request becomes an Exception entry
         (the chunk retries its siblings one-by-one through the sequential
-        reference, so one poison request never drops a batch).
+        reference, so one poison request never drops a batch; each re-run
+        counts in ``stats["fallback_dispatches"]``).
         ``on_chunk(indices, results)`` fires after every dispatched chunk —
         the overlap hook ``plan_many`` builds plans on."""
         if errors not in ("raise", "isolate"):
@@ -297,6 +301,7 @@ class PlanEngine:
                     # sequential reference so siblings still get served
                     res = []
                     for i in chunk:
+                        self.stats["fallback_dispatches"] += 1
                         try:
                             res.append(select_k_and_cluster(
                                 norm[i], seed=seeds[i],

@@ -12,9 +12,10 @@ from __future__ import annotations
 from dataclasses import asdict, dataclass, field
 
 from repro.sim.simulate import (
-    METRIC_NAMES, SamplingPlan, _metric_arrays, full_metrics, reconstruct,
-    sim_wall_time, simulate_program,
+    METRIC_NAMES, SamplingPlan, full_metrics, reconstruct, sim_wall_time,
+    simulate_program,
 )
+from repro.sim.timing import BatchKernelMetrics
 from repro.tracing.programs import Program
 
 
@@ -48,8 +49,10 @@ def evaluate_metrics(plan: SamplingPlan, metrics,
                      program: str = "", platform: str = "") -> EvalResult:
     """Evaluate a plan against already-simulated per-kernel metrics
     (``BatchKernelMetrics`` from the vectorized path, or a legacy
-    ``list[KernelMetrics]``)."""
-    m = _metric_arrays(metrics)
+    ``list[KernelMetrics]``): the result reports every metric, so a list
+    entry must carry every ``KernelMetrics`` field."""
+    m = (metrics if isinstance(metrics, BatchKernelMetrics)
+         else BatchKernelMetrics.from_list(list(metrics)))
     full = full_metrics(m)
     sampled = reconstruct(plan, m)
     reps = plan.rep_indices()
@@ -66,8 +69,8 @@ def evaluate_metrics(plan: SamplingPlan, metrics,
         num_kernels=len(metrics), num_clusters=plan.num_clusters,
         num_reps=len(reps), error_pct=error,
         speedup=full_t / max(rep_t, 1e-12),
-        sim_time_full_s=sim_wall_time(metrics),
-        sim_time_sampled_s=sim_wall_time(metrics, reps),
+        sim_time_full_s=sim_wall_time(m),
+        sim_time_sampled_s=sim_wall_time(m, reps),
         full=full, sampled=sampled,
         timings=dict(plan.extra.get("timings", {})),
     )

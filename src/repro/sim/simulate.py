@@ -13,6 +13,7 @@ metric arrays directly instead of looping kernels.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from types import SimpleNamespace
 
 import numpy as np
 
@@ -53,17 +54,23 @@ def simulate_program(program: Program,
         stack_stats([k.stats(platform) for k in program.kernels]), hw)
 
 
-def _metric_arrays(metrics):
-    """(cycles, per-metric arrays) for list-of-KernelMetrics or
-    BatchKernelMetrics inputs — the batch form is a zero-copy view."""
-    if not isinstance(metrics, BatchKernelMetrics):
-        metrics = BatchKernelMetrics.from_list(list(metrics))
-    return metrics
+def _metric_arrays(metrics, names):
+    """The named per-kernel metric arrays (float64) of a
+    ``BatchKernelMetrics`` (zero-copy) or of any sequence of per-kernel
+    records.  Each formula asks only for the fields it reads — eq. 5 the
+    cycles and cycle-weighted rates (``METRIC_NAMES``), eq. 6 ``time_s``,
+    §5.4 ``sim_time_s`` — so a record needs no other field."""
+    if isinstance(metrics, BatchKernelMetrics):
+        return SimpleNamespace(**{n: getattr(metrics, n) for n in names})
+    metrics = list(metrics)
+    return SimpleNamespace(**{
+        n: np.array([getattr(m, n) for m in metrics], np.float64)
+        for n in names})
 
 
 def _weighted_metrics(metrics, weights, indices=None):
     """Aggregate: cycles = weighted sum; rates/IPC = cycle-weighted mean."""
-    m = _metric_arrays(metrics)
+    m = _metric_arrays(metrics, METRIC_NAMES)
     cycles = m.cycles if indices is None else m.cycles[indices]
     w = np.asarray(weights, np.float64)
     tot_cycles = float(np.sum(cycles * w))
@@ -104,7 +111,7 @@ def sampling_error(plan: SamplingPlan, metrics, name="cycles"):
 
 def speedup(plan: SamplingPlan, metrics) -> float:
     """Paper eq. 6: full kernel execution time / representative exec time."""
-    m = _metric_arrays(metrics)
+    m = _metric_arrays(metrics, ("time_s",))
     # sequential sums (not np pairwise) keep the golden fixture bit-stable
     full_t = sum(m.time_s.tolist())
     rep_t = sum(m.time_s[plan.rep_indices()].tolist())
@@ -113,7 +120,7 @@ def speedup(plan: SamplingPlan, metrics) -> float:
 
 def sim_wall_time(metrics, indices=None) -> float:
     """End-to-end simulator wall-time (§5.4) for all or selected kernels."""
-    m = _metric_arrays(metrics)
+    m = _metric_arrays(metrics, ("sim_time_s",))
     if indices is None:
         return sum(m.sim_time_s.tolist())
     return sum(m.sim_time_s[np.asarray(list(indices), int)].tolist())

@@ -1,9 +1,9 @@
 """Known-bad R1: host syncs inside shard_map-traced bodies (both the
-``jax.experimental.shard_map`` import and the graduated ``jax.shard_map``
-alias must mark the body as traced)."""
+``from jax import shard_map`` spelling and the ``jax.shard_map`` attribute
+must mark the body as traced)."""
 import jax
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 

@@ -2,7 +2,7 @@
 cross-shard reductions via collectives, never host round-trips)."""
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 

@@ -2,7 +2,7 @@
 (every shard would draw IDENTICAL noise — data-parallel augmentation
 silently degenerates to one effective sample)."""
 import jax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 

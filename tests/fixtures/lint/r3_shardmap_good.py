@@ -1,7 +1,7 @@
 """Known-good R3: the key enters as an argument, is folded per shard
 (axis_index keeps shards decorrelated), and split once per consumer."""
 import jax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 

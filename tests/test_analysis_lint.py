@@ -121,8 +121,8 @@ def test_r1_flags_both_traced_and_dispatch_loop_sites():
 
 def test_r1_shard_map_bodies_are_traced():
     """Both spellings mark the wrapped body traced: the
-    jax.experimental.shard_map import AND the graduated jax.shard_map
-    alias (each fixture body syncs, so each must be flagged)."""
+    ``from jax import shard_map`` import AND the ``jax.shard_map``
+    attribute (each fixture body syncs, so each must be flagged)."""
     findings = [f for f in lint_paths([FIXTURES / "r1_shardmap_bad.py"])
                 if f.rule == "R1"]
     symbols = {f.symbol for f in findings}

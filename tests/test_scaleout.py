@@ -128,7 +128,7 @@ def test_compressed_psum_mean_tracks_exact():
     """The error-feedback int8 collective must agree with the exact f32
     psum mean within the int8 quantization grid (amax/127 per tensor),
     and its residual must be exactly what went uncommunicated."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.optim.grad_compress import compressed_psum_mean, psum_mean
