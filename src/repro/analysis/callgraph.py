@@ -180,11 +180,14 @@ class ModuleIndex(ast.NodeVisitor):
         """Resolve a callee expression to a dotted string: either an
         external path ("jax.lax.scan", "numpy.asarray") or a local id
         ("<module>:<qual>").  ``self.x`` resolves within the enclosing
-        class; ``functools.partial(f, ...)`` unwraps to ``f``."""
+        class; ``functools.partial(f, ...)`` unwraps to ``f``, as does
+        ``repro.telemetry.carry(f)`` (``f`` run under the caller's span
+        context)."""
         if isinstance(node, ast.Call):  # partial(f, ...) / jit(f) chains
             inner = self.resolve(node.func)
             if inner in ("functools.partial", "jax.jit", "jax.vmap",
-                         "jax.pmap", "jax.checkpoint", "jax.remat"):
+                         "jax.pmap", "jax.checkpoint", "jax.remat",
+                         "repro.telemetry.carry"):
                 for arg in node.args:
                     r = self.resolve(arg)
                     if r is not None:

@@ -29,6 +29,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
+
 #: process-wide swept-engine instrumentation: `builds` counts compiled
 #: executables (cache misses), `dispatches` counts engine invocations
 ENGINE_STATS = {"builds": 0, "dispatches": 0}
@@ -606,42 +608,46 @@ def sweep_cluster_stack(
     pmask = np.zeros((B, n_pad), np.float32)
     silm = np.zeros((B, n_pad), np.float32)
     init_idx = np.zeros((B, k_max), np.int32)
-    for row, i in enumerate(todo):
-        x = xs[i]
-        n = len(x)
-        xb[row, :n] = x
-        pmask[row, :n] = 1.0
-        sil_idx = sil_idxs[i]
-        if sil_idx is None:
-            silm[row, :n] = 1.0
-        else:
-            silm[row, sil_idx] = 1.0
-        k_up = min(k_max, n - 1)
-        if init == "device":
-            init_idx[row, :k_up] = device_init_indices(x, seeds[i], k_up)
-        else:
-            init_idx[row, :k_up] = _kmeanspp_init(x, k_up, seeds[i])
+    with telemetry.span("plan.seed"):
+        for row, i in enumerate(todo):
+            x = xs[i]
+            n = len(x)
+            xb[row, :n] = x
+            pmask[row, :n] = 1.0
+            sil_idx = sil_idxs[i]
+            if sil_idx is None:
+                silm[row, :n] = 1.0
+            else:
+                silm[row, sil_idx] = 1.0
+            k_up = min(k_max, n - 1)
+            if init == "device":
+                init_idx[row, :k_up] = device_init_indices(x, seeds[i], k_up)
+            else:
+                init_idx[row, :k_up] = _kmeanspp_init(x, k_up, seeds[i])
 
     shards = _effective_shards(B, data_shards)
-    fn = _sweep_fn(B, n_pad, d, k_max, iters, use_pallas, blk, shards)
-    ENGINE_STATS["dispatches"] += 1
-    if shards > 1:
-        args = _shard_args((xb, pmask, init_idx, silm), shards)
-    else:
-        args = (jnp.asarray(xb), jnp.asarray(pmask), jnp.asarray(init_idx),
-                jnp.asarray(silm))
-    if B > 1:
-        labels_all, sil, ok = fn(*args)
-    else:
-        labels_all, sil, ok = (jnp.expand_dims(r, 0) for r in
-                               fn(*(a[0] for a in args)))
-    labels_all = np.asarray(labels_all)
-    sil = np.asarray(sil)
-    ok = np.asarray(ok)
+    with telemetry.span("plan.sweep", points=sum(len(xs[i]) for i in todo),
+                        k_max=k_max):
+        fn = _sweep_fn(B, n_pad, d, k_max, iters, use_pallas, blk, shards)
+        ENGINE_STATS["dispatches"] += 1
+        if shards > 1:
+            args = _shard_args((xb, pmask, init_idx, silm), shards)
+        else:
+            args = (jnp.asarray(xb), jnp.asarray(pmask),
+                    jnp.asarray(init_idx), jnp.asarray(silm))
+        if B > 1:
+            labels_all, sil, ok = fn(*args)
+        else:
+            labels_all, sil, ok = (jnp.expand_dims(r, 0) for r in
+                                   fn(*(a[0] for a in args)))
+        labels_all = np.asarray(labels_all)
+        sil = np.asarray(sil)
+        ok = np.asarray(ok)
     ks = list(range(2, k_max + 1))
-    for row, i in enumerate(todo):
-        out[i] = _finish_one(labels_all[row], sil[row], ok[row], len(xs[i]),
-                             ks, sil_floor, tie_tol)
+    with telemetry.span("plan.select"):
+        for row, i in enumerate(todo):
+            out[i] = _finish_one(labels_all[row], sil[row], ok[row],
+                                 len(xs[i]), ks, sil_floor, tie_tol)
     return out
 
 

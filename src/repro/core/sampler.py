@@ -172,12 +172,12 @@ class GCLSampler:
         materialized path) and embedded via `embed_stream`, so peak graph
         residency stays bounded by one micro-batch instead of 2x the
         program (PR 3's guarantee, previously bypassed here)."""
-        t0 = time.time()
+        t0 = time.perf_counter()
         train_info = self.train_stream(self.iter_graphs(program),
                                        n_total=len(program), verbose=verbose)
-        t2 = time.time()
+        t2 = time.perf_counter()
         emb = self.embed_stream(self.iter_graphs(program))
-        t3 = time.time()
+        t3 = time.perf_counter()
         seqs = np.array([k.seq for k in program.kernels])
         plan = self.cluster(emb, seqs)
         plan.extra.update(
@@ -185,7 +185,7 @@ class GCLSampler:
             embed=dict(self.trainer.embed_stats),
             timings={
                 "train_s": t2 - t0,  # includes the lazy trace->graph pass
-                "embed_s": t3 - t2, "cluster_s": time.time() - t3,
+                "embed_s": t3 - t2, "cluster_s": time.perf_counter() - t3,
             },
         )
         return plan

@@ -50,6 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.checkpoint.manager import CheckpointManager
 from repro.config import TrainConfig
 from repro.core import rgcn as rgcn_mod
@@ -79,6 +80,10 @@ class FitInterrupted(RuntimeError):
     training job without killing the process."""
 
 
+#: what ``_OneAhead._next`` returns for an exhausted source
+_END = object()
+
+
 class _OneAhead:
     """One-slot host->device staging pipeline (DESIGN.md §12).
 
@@ -95,7 +100,11 @@ class _OneAhead:
     ``enabled=False`` degrades to inline staging (the parity baseline);
     ``stage_s`` (host seconds spent staging) and ``wait_s`` (main-thread
     seconds blocked waiting for a stage) quantify the overlap:
-    ``overlap_fraction = 1 - wait_s / stage_s``.
+    ``overlap_fraction = 1 - wait_s / stage_s``.  Each stage is a
+    ``<span>.stage`` span, each wait a ``<span>.wait`` span, and each pull
+    from ``items`` (a lazy source's own work, such as an ingest pass) a
+    ``<span>.next`` span (repro.telemetry); the staging thread carries
+    the caller's span context.
 
     ``depth=k`` keeps up to k staged items queued ahead of the consumer
     (still ONE worker thread, so items stage strictly in submission order
@@ -105,20 +114,28 @@ class _OneAhead:
     residency is bounded by ``depth + 1``.
     """
 
-    def __init__(self, stage, items, *, enabled: bool = True, depth: int = 1):
+    def __init__(self, stage, items, *, span: str, enabled: bool = True,
+                 depth: int = 1):
         self._stage = stage
         self._items = items
+        self._stage_span = f"{span}.stage"
+        self._wait_span = f"{span}.wait"
+        self._next_span = f"{span}.next"
         self.enabled = bool(enabled)
         self.depth = max(1, int(depth))
         self.stage_s = 0.0
         self.wait_s = 0.0
 
     def _timed_stage(self, item):
-        t = time.time()
-        try:
-            return self._stage(item)
-        finally:
-            self.stage_s += time.time() - t
+        with telemetry.span(self._stage_span, timed=True) as s:
+            staged = self._stage(item)
+        self.stage_s += s.seconds
+        return staged
+
+    def _next(self, it):
+        """The next item of ``it``, or ``_END`` when it is exhausted."""
+        with telemetry.span(self._next_span):
+            return next(it, _END)
 
     @property
     def overlap_fraction(self) -> float:
@@ -129,7 +146,8 @@ class _OneAhead:
     def __iter__(self):
         it = iter(self._items)
         if not self.enabled:
-            for item in it:  # inline staging: all staging time is wait time
+            # inline staging: all staging time is wait time
+            while (item := self._next(it)) is not _END:
                 staged = self._timed_stage(item)
                 self.wait_s = self.stage_s
                 yield item, staged
@@ -140,22 +158,23 @@ class _OneAhead:
                                   thread_name_prefix="stage-prefetch")
         try:
             def task():
-                try:
-                    item = next(it)
-                except StopIteration:
+                item = self._next(it)
+                if item is _END:
                     return None
                 return item, self._timed_stage(item)
 
             from collections import deque
 
-            q = deque(pool.submit(task) for _ in range(self.depth))
+            q = deque(pool.submit(telemetry.carry(task))
+                      for _ in range(self.depth))
             while True:
-                t = time.time()
-                res = q.popleft().result()
-                self.wait_s += time.time() - t
+                with telemetry.span(self._wait_span, timed=True) as w:
+                    res = q.popleft().result()
+                self.wait_s += w.seconds
                 if res is None:
                     return
-                q.append(pool.submit(task))  # refill the look-ahead window
+                # refill the look-ahead window
+                q.append(pool.submit(telemetry.carry(task)))
                 yield res
         finally:
             pool.shutdown(wait=True)
@@ -392,6 +411,12 @@ class ContrastiveTrainer:
         :func:`fit_resilient` can shrink the mesh and resume — losing at
         most the current chunk, never the fit.
         """
+        with telemetry.span("train.fit", root=True):
+            return self._fit(graphs, verbose, checkpoint_dir, resume,
+                             interrupt_after, fault_hook, watchdog)
+
+    def _fit(self, graphs, verbose, checkpoint_dir, resume,
+             interrupt_after, fault_hook, watchdog):
         tc, rc = self.tc, self.rc
         rng_np = np.random.default_rng(tc.seed)
         n = len(graphs)
@@ -474,7 +499,7 @@ class ContrastiveTrainer:
             warnings.warn(
                 f"training packed {trunc_nodes} node(s) over the per-graph "
                 f"budget; graphs were truncated (see batching caps)",
-                stacklevel=2,
+                stacklevel=3,  # the caller of fit
             )
         info["trunc_nodes"] = trunc_nodes
         return state.params, info
@@ -533,7 +558,8 @@ class ContrastiveTrainer:
         tc = self.tc
         eng = self._engine()
         wd_fired0 = watchdog.fired if watchdog is not None else 0
-        plan = plan_epoch(graphs, selections, **caps)
+        with telemetry.span("fit.plan_epoch", steps=len(selections)):
+            plan = plan_epoch(graphs, selections, **caps)
         steps = plan.n_steps
         chunk_len = min(tc.scan_chunk, bucket_size(max(steps, 1), 1))
 
@@ -587,33 +613,40 @@ class ContrastiveTrainer:
             overlap cannot change the math."""
             seg, lo, hi = desc
             r0, r1 = lo - seg.start, hi - seg.start
-            rows_np = {}
-            for f, arr in seg.batches.items():
-                rows = arr[r0:r1]
-                if len(rows) < chunk_len:  # edge-pad dead tail steps
-                    pad = np.repeat(rows[-1:], chunk_len - len(rows),
-                                    axis=0)
-                    rows = np.concatenate([rows, pad], axis=0)
-                rows_np[f] = rows
-            # multi-device staging: each device receives only its own
-            # shard of the batch axes (leading scan-steps axis stays
-            # replicated); plain upload on a 1-device data axis
-            stacked = shard_batch_put(rows_np, self.mesh_rules, leading=1)
+            with telemetry.span("fit.pack"):
+                rows_np = {}
+                for f, arr in seg.batches.items():
+                    rows = arr[r0:r1]
+                    if len(rows) < chunk_len:  # edge-pad dead tail steps
+                        pad = np.repeat(rows[-1:], chunk_len - len(rows),
+                                        axis=0)
+                        rows = np.concatenate([rows, pad], axis=0)
+                    rows_np[f] = rows
+                # multi-device staging: each device receives only its own
+                # shard of the batch axes (leading scan-steps axis stays
+                # replicated); plain upload on a 1-device data axis
+                stacked = shard_batch_put(rows_np, self.mesh_rules,
+                                          leading=1)
             abs_idx = np.arange(lo, lo + chunk_len)
             live = (abs_idx < hi) & (abs_idx >= start_step)
-            keys = jax.vmap(
-                lambda i: jax.random.fold_in(base_key, i)
-            )(jnp.asarray(abs_idx))
+            # eager device ops, which can wait behind the running chunk
+            with telemetry.span("fit.keys"):
+                keys = jax.vmap(
+                    lambda i: jax.random.fold_in(base_key, i)
+                )(jnp.asarray(abs_idx))
             return stacked, keys, live
 
-        pipe = _OneAhead(stage_chunk, chunk_descs(), enabled=tc.prefetch,
-                         depth=tc.prefetch_depth)
+        pipe = _OneAhead(stage_chunk, chunk_descs(), span="fit",
+                         enabled=tc.prefetch, depth=tc.prefetch_depth)
         for (_, _, hi), (stacked, keys, live) in pipe:
             n_chunks += 1
             if watchdog is not None:
                 watchdog.step_start()
-            state, ys = eng.scan(state, stacked, keys,
-                                 jnp.asarray(live))
+            # the dispatch only: the chunk runs on after the span closes
+            with telemetry.span("fit.chunk", computed=chunk_len,
+                                live=int(live.sum())):
+                state, ys = eng.scan(state, stacked, keys,
+                                     jnp.asarray(live))
             pending.append((ys, live))
             if watchdog is not None:
                 # SLO timing needs REAL chunk completion — an opt-in
@@ -756,12 +789,16 @@ class ContrastiveTrainer:
         than the budget is truncated (with accounting) instead of silently
         blowing the bucket past the Pallas kernel's VMEM budget.
         Returns (device batch, PackMeta, bucket key)."""
-        packed, meta = pack_graphs(
-            bin_graphs,
-            pad_graphs_to=bucket_size(len(bin_graphs), 8),
-            max_nodes_per_graph=n_cap, max_edges_per_graph=e_cap,
-        )
-        batch = {k: jnp.asarray(v) for k, v in packed.items()}
+        with telemetry.span("embed.pack", graphs=len(bin_graphs)) as s:
+            packed, meta = pack_graphs(
+                bin_graphs,
+                pad_graphs_to=bucket_size(len(bin_graphs), 8),
+                max_nodes_per_graph=n_cap, max_edges_per_graph=e_cap,
+            )
+            s.count(real_nodes=int(meta.node_off[-1]),
+                    padded_nodes=len(packed["token"]))
+        with telemetry.span("embed.upload"):
+            batch = {k: jnp.asarray(v) for k, v in packed.items()}
         return batch, meta, bucket_key(packed)
 
     def _embed_finish(self, label, hashes, fn, stats):
@@ -829,10 +866,11 @@ class ContrastiveTrainer:
             return sel, self._stage_bin(
                 [graphs[i] for i in sel], n_cap, e_cap)
 
-        pipe = _OneAhead(stage, bins, enabled=self.tc.prefetch,
+        pipe = _OneAhead(stage, bins, span="embed", enabled=self.tc.prefetch,
                          depth=self.tc.prefetch_depth)
         for _, (sel, (batch, meta, bkey)) in pipe:
-            z = np.asarray(fn(params, batch))
+            with telemetry.span("embed.encode", graphs=len(sel)):
+                z = np.asarray(fn(params, batch))
             trunc_nodes += int(meta.trunc_nodes.sum())
             trunc_edges += int(meta.trunc_edges.sum())
             bucket_keys.add(bkey)
@@ -904,10 +942,11 @@ class ContrastiveTrainer:
                 pending(), lambda hg: (hg[1].n_nodes, hg[1].n_edges),
                 max_nodes=n_cap, max_edges=e_cap, max_graphs=batch_size,
                 stats=stream_stats),
-            enabled=self.tc.prefetch,
+            span="embed", enabled=self.tc.prefetch,
         )
         for bin_items, (batch, meta, bkey) in pipe:
-            z = np.asarray(fn(params, batch))
+            with telemetry.span("embed.encode", graphs=len(bin_items)):
+                z = np.asarray(fn(params, batch))
             trunc_nodes += int(meta.trunc_nodes.sum())
             trunc_edges += int(meta.trunc_edges.sum())
             bucket_keys.add(bkey)

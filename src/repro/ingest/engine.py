@@ -33,6 +33,7 @@ from typing import Iterator, Optional
 from repro.config import resolve_trace_caps
 from repro.core.graphs import KernelGraph, build_kernel_graph
 from repro.ingest.store import GraphStore, kernel_graph_key
+from repro.telemetry import carry, span
 
 
 @dataclass(frozen=True)
@@ -106,7 +107,8 @@ class IngestEngine:
                     return g
                 if existed:  # present on disk but rejected -> corrupt entry
                     self._bump("corrupt")
-            g = build_kernel_graph(inv.trace(cap_warps, cap_instr))
+            with span("ingest.build"):
+                g = build_kernel_graph(inv.trace(cap_warps, cap_instr))
             self._bump("traced")
             if store is not None:
                 store.save_kernel(key, g)
@@ -146,7 +148,7 @@ class IngestEngine:
                 it = iter(kernels)
                 for inv in it:
                     pending.append(
-                        pool.submit(self._build_one, inv, cap_warps,
+                        pool.submit(carry(self._build_one), inv, cap_warps,
                                     cap_instr))
                     if len(pending) >= window:
                         break
@@ -157,8 +159,8 @@ class IngestEngine:
                     nxt = next(it, None)
                     if nxt is not None:
                         pending.append(
-                            pool.submit(self._build_one, nxt, cap_warps,
-                                        cap_instr))
+                            pool.submit(carry(self._build_one), nxt,
+                                        cap_warps, cap_instr))
                     yield g
         if self.store is not None and self.config.cache and kernels:
             keys = [kernel_graph_key(k, cap_warps, cap_instr)

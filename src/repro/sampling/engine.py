@@ -44,6 +44,7 @@ from typing import Callable, Optional
 import jax
 import numpy as np
 
+from repro import telemetry
 from repro.core.clustering import (
     bucket_batch, bucket_points, engine_stats, select_k_and_cluster,
     sweep_cluster_stack, warm_sweep,
@@ -346,9 +347,10 @@ class PlanEngine:
                 labels, info = r
                 req = requests[i]
                 try:
-                    plans[i] = plan_from_labels(
-                        labels, req.seqs, req.method,
-                        extra=dict(info, **req.extra))
+                    with telemetry.span("plan.build"):
+                        plans[i] = plan_from_labels(
+                            labels, req.seqs, req.method,
+                            extra=dict(info, **req.extra))
                 except Exception as e:
                     if errors == "raise":
                         raise
@@ -363,7 +365,7 @@ class PlanEngine:
                 results = self.cluster_many(
                     embs, seeds, errors=errors,
                     on_chunk=lambda idxs, res: futs.append(
-                        pool.submit(build, idxs, res)))
+                        pool.submit(telemetry.carry(build), idxs, res)))
                 for f in futs:
                     f.result()
             # normalization failures never reach a chunk — pick the

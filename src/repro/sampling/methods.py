@@ -21,6 +21,7 @@ from typing import Optional
 
 import numpy as np
 
+from repro import telemetry
 from repro.core.baselines.pka import pka_features
 from repro.core.baselines.sieve import sieve_partition
 from repro.core.baselines.stem_root import stem_root_partition, stem_root_times
@@ -153,10 +154,15 @@ class GCLMethod(SamplingMethod):
         return f"{base}-{prov}" if prov else base
 
     def prepare(self, program: Program) -> Artifacts:
+        with telemetry.span("gcl.prepare", root=True,
+                            invocations=len(program)):
+            return self._prepare(program)
+
+    def _prepare(self, program: Program) -> Artifacts:
         stream = self._use_streaming(program)
-        t0 = time.time()
+        t0 = time.perf_counter()
         graphs = None if stream else self.sampler.build_graphs(program)
-        t1 = time.time()
+        t1 = time.perf_counter()
         meta: dict = {"streaming": stream}
         if self.sampler.params is None:
             ckpt = dict(checkpoint_dir=self._fit_checkpoint_dir(program),
@@ -179,7 +185,7 @@ class GCLMethod(SamplingMethod):
         else:
             meta["encoder_reused"] = True
         meta["trained_on"] = self._trained_on
-        t2 = time.time()
+        t2 = time.perf_counter()
         if stream:
             # second lazy pass: graphs flow through pack/encode one
             # micro-batch at a time (bounded peak residency; the
@@ -192,7 +198,7 @@ class GCLMethod(SamplingMethod):
             }
         else:
             emb = self.sampler.embed(graphs)
-        t3 = time.time()
+        t3 = time.perf_counter()
         ing = self.sampler.ingest
         meta["ingest"] = {
             "workers": self.cfg.ingest.workers,
@@ -230,13 +236,14 @@ class GCLMethod(SamplingMethod):
         """All programs of the batch through the compiled planning engine:
         one multi-K sweep dispatch per embedding-size bucket, `use_pallas`
         threaded through from the RGCN config."""
-        t0 = time.time()
-        engine = self.sampler.plan_engine()
-        plans = engine.plan_many([
-            PlanRequest(np.asarray(a.payload["embeddings"]),
-                        np.asarray(a.payload["seqs"]), self.display_name)
-            for _, a in items])
-        cluster_s = (time.time() - t0) / max(len(items), 1)
+        with telemetry.span("gcl.plan", root=True, timed=True,
+                            programs=len(items)) as s:
+            engine = self.sampler.plan_engine()
+            plans = engine.plan_many([
+                PlanRequest(np.asarray(a.payload["embeddings"]),
+                            np.asarray(a.payload["seqs"]), self.display_name)
+                for _, a in items])
+        cluster_s = s.seconds / max(len(items), 1)
         for (_, artifacts), plan in zip(items, plans):
             plan.extra["timings"] = dict(artifacts.timings,
                                          cluster_s=cluster_s)
@@ -266,10 +273,10 @@ class PKAMethod(SamplingMethod):
                 "seed": self.seed}
 
     def prepare(self, program: Program) -> Artifacts:
-        t0 = time.time()
+        t0 = time.perf_counter()
         x = pka_features(program, self.platform)
         return _artifacts(self, program, {"features": x},
-                          {"features_s": time.time() - t0})
+                          {"features_s": time.perf_counter() - t0})
 
     def plan(self, program: Program, artifacts: Artifacts) -> SamplingPlan:
         return self.plan_batch([(program, artifacts)])[0]
@@ -282,13 +289,13 @@ class PKAMethod(SamplingMethod):
             extra={"timings": dict(artifacts.timings)})
 
     def plan_batch(self, items: list) -> list[SamplingPlan]:
-        t0 = time.time()
+        t0 = time.perf_counter()
         engine = PlanEngine(k_max=self.k_max, seed=self.seed)
         plans = engine.plan_many([
             PlanRequest(np.asarray(a.payload["features"]), _seqs(p),
                         self.display_name)
             for p, a in items])
-        cluster_s = (time.time() - t0) / max(len(items), 1)
+        cluster_s = (time.perf_counter() - t0) / max(len(items), 1)
         for (_, artifacts), plan in zip(items, plans):
             plan.extra["timings"] = dict(artifacts.timings,
                                          cluster_s=cluster_s)
@@ -307,10 +314,10 @@ class SieveMethod(SamplingMethod):
         return {"platform": self.platform}
 
     def prepare(self, program: Program) -> Artifacts:
-        t0 = time.time()
+        t0 = time.perf_counter()
         labels, ctas = sieve_partition(program, self.platform)
         return _artifacts(self, program, {"labels": labels, "priority": ctas},
-                          {"partition_s": time.time() - t0})
+                          {"partition_s": time.perf_counter() - t0})
 
     def plan(self, program: Program, artifacts: Artifacts) -> SamplingPlan:
         plan = plan_from_labels(
@@ -334,10 +341,10 @@ class StemRootMethod(SamplingMethod):
         return {"platform": self.platform, "eps": self.eps}
 
     def prepare(self, program: Program) -> Artifacts:
-        t0 = time.time()
+        t0 = time.perf_counter()
         times = stem_root_times(program, self.platform)
         return _artifacts(self, program, {"times": times},
-                          {"profile_s": time.time() - t0})
+                          {"profile_s": time.perf_counter() - t0})
 
     def plan(self, program: Program, artifacts: Artifacts) -> SamplingPlan:
         names = [k.name for k in program.kernels]

@@ -53,6 +53,16 @@ def parse_buckets(spec: str) -> list[tuple[int, int]]:
     return out
 
 
+def _summary_ms(seconds: list) -> dict:
+    """p50, p99 and mean of a list of durations, in milliseconds (None
+    for an empty list)."""
+    ms = np.asarray(seconds) * 1e3
+    if not len(ms):
+        return {"p50": None, "p99": None, "mean": None}
+    return {"p50": float(np.percentile(ms, 50)),
+            "p99": float(np.percentile(ms, 99)), "mean": float(ms.mean())}
+
+
 @dataclass
 class _Pending:
     request: PlanRequest
@@ -115,6 +125,7 @@ class PlanService:
         return {
             "submitted": 0, "served": 0, "failed": 0, "dispatches": 0,
             "batch_sizes": [], "dispatch_s": [], "latencies_s": [],
+            "queue_waits_s": [],
             "queue_depth_samples": [], "sanitize_trips": 0,
             "flush_causes": {"fill": 0, "deadline": 0, "drain": 0},
         }
@@ -195,12 +206,8 @@ class PlanService:
                  for k, v in self.metrics.items()}
         with self._cv:
             m["queue_depth"] = sum(len(q) for q in self._queues.values())
-        lat = np.asarray(m.pop("latencies_s")) * 1e3
-        m["latency_ms"] = {
-            "p50": float(np.percentile(lat, 50)) if len(lat) else None,
-            "p99": float(np.percentile(lat, 99)) if len(lat) else None,
-            "mean": float(lat.mean()) if len(lat) else None,
-        }
+        m["latency_ms"] = _summary_ms(m.pop("latencies_s"))
+        m["queue_wait_ms"] = _summary_ms(m.pop("queue_waits_s"))
         sizes = m.pop("batch_sizes")
         m["batch_occupancy"] = (float(np.mean(sizes)) / self.max_batch
                                 if sizes else None)
@@ -261,9 +268,12 @@ class PlanService:
                 pending = [q.popleft() for _ in range(n)]
                 cause = ("fill" if n >= self.max_batch else
                          "drain" if self._stop else "deadline")
-            self._dispatch(key, pending, cause)
+            self._dispatch(key, pending, cause, now)
 
-    def _dispatch(self, key, pending, cause: str):
+    def _dispatch(self, key, pending, cause: str, t_pop: float):
+        """Serve one popped batch; ``t_pop`` ends each request's queue
+        wait (submit to pop)."""
+        waits = [t_pop - p.t_submit for p in pending]
         reqs = [p.request for p in pending]
         t0 = time.perf_counter()
         try:
@@ -294,6 +304,7 @@ class PlanService:
             m["served"] += served
             m["failed"] += failed
             m["latencies_s"].extend(lats)
+            m["queue_waits_s"].extend(waits)
 
     def _sanitize_plan(self, plan):
         """NaN/inf tripwire per served plan (``sanitize=True``).  Returns

@@ -1,6 +1,7 @@
 """repro.serving: continuous batcher, warm pool, loadgen, tenant serving."""
 
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -222,4 +223,22 @@ def test_stats_reset_windows_counters():
         svc.reset_stats()
         s = svc.stats()
         assert s["served"] == 0 and s["latency_ms"]["p50"] is None
+        assert s["queue_wait_ms"]["p50"] is None
         assert s["engine"]["programs"] == 0
+
+
+def test_queue_wait_runs_from_submit_to_dispatch():
+    """stats()["queue_wait_ms"] times each request from submit to the
+    dispatcher's pop: three requests wait for a fourth to fill the batch,
+    which then waits for nothing."""
+    with PlanService(max_batch=4, max_delay_ms=10_000.0, **KW) as svc:
+        futs = [svc.submit(_req(40, seed=i)) for i in range(3)]
+        time.sleep(0.2)
+        futs.append(svc.submit(_req(40, seed=3)))
+        for f in futs:
+            assert isinstance(f.result(30.0), SamplingPlan)
+        s = svc.stats()
+    q = s["queue_wait_ms"]
+    # the first three waited >= 0.2 s, the fourth about nothing
+    assert 190.0 <= q["p50"] <= q["p99"] < s["latency_ms"]["p99"]
+    assert 0.75 * 190.0 <= q["mean"] < q["p99"]
