@@ -27,8 +27,9 @@ Resume (DESIGN.md §6): with ``checkpoint_dir`` the scan engine snapshots
 (TrainState, base RNG key, metrics history, step cursor) every
 ``checkpoint_every`` steps through `repro.checkpoint.CheckpointManager`; an
 interrupted fit restarted with the same config replays the deterministic
-epoch plan and continues from the cursor BIT-EXACTLY (chunks are masked per
-step, so chunk boundaries never change the math).
+epoch plan and continues from the cursor BIT-EXACTLY (a chunk's padded and
+already-done steps are passed through by a branch, so chunk boundaries never
+change the math).
 
 Distribution: batches shard over the mesh's batch axes (the packed
 node/edge/graph axes carry the 'batch' logical name — see
@@ -192,8 +193,9 @@ class GCLTrainConfig:
     #: 'python' = the pre-engine per-step loop, kept as a parity shim
     engine: str = "scan"
     #: scan chunk length (fixed per fit: chunks shorter than this are padded
-    #: with masked no-op steps, so ONE executable per bucket serves any step
-    #: count).  Effective length is min(scan_chunk, next_pow2(steps)).
+    #: with dead steps that a branch passes through at no step compute, so
+    #: ONE executable per bucket serves any step count).  Effective length
+    #: is min(scan_chunk, next_pow2(steps)).
     scan_chunk: int = 32
     #: snapshot (state, rng, history, cursor) every N steps (0 = off;
     #: scan engine only) — cadence is rounded up to chunk boundaries
@@ -286,17 +288,25 @@ def _engine_fns(rc: RGCNConfig, opt: TrainConfig, tau: float,
         return state, dict(metrics, loss=loss, **opt_metrics)
 
     def chunk(state: TrainState, stacked, keys, live):
-        """One fixed-length scan segment.  `live` masks padded / already-done
-        steps: a dead step still computes (fixed shapes) but its state update
-        and metrics are discarded, which makes chunk boundaries — and hence
-        resume points — bit-neutral."""
+        """One fixed-length scan segment.  `live` flags the real steps; a
+        padded / already-done step takes the other branch of a
+        ``lax.cond``, which passes the state through and emits a zero
+        metrics row (dropped on the host), so it costs no step compute.
+        A live step is the same step on the same rows with the same key
+        wherever it sits in a chunk, which makes chunk boundaries — and
+        hence resume points — bit-neutral."""
 
         def body(st, xs):
             batch, k, lv = xs
-            new_st, m = step(st, batch, k)
-            st = jax.tree_util.tree_map(
-                lambda new, old: jnp.where(lv, new, old), new_st, st)
-            return st, jnp.stack([m[x] for x in METRIC_KEYS])
+
+            def run(st):
+                st, m = step(st, batch, k)
+                return st, jnp.stack([m[x] for x in METRIC_KEYS])
+
+            def skip(st):
+                return st, jnp.zeros(len(METRIC_KEYS), jnp.float32)
+
+            return jax.lax.cond(lv, run, skip, st)
 
         return jax.lax.scan(body, state, (stacked, keys, live))
 
@@ -550,11 +560,11 @@ class ContrastiveTrainer:
                   fault_hook=None, watchdog=None):
         """Compiled engine: pre-packed epoch plan, per-segment device
         staging (sharded over the mesh's batch axes under MeshRules),
-        fixed-length masked scan chunks, log_every-gated host syncs,
-        chunk-boundary checkpoints.  With ``tc.prefetch`` the host side of
-        chunk i+1 (row slicing + shard_batch_put + key derivation) rides a
-        background thread behind chunk i's async dispatch (_OneAhead) —
-        bit-exact either way."""
+        fixed-length scan chunks whose dead steps are branched past,
+        log_every-gated host syncs, chunk-boundary checkpoints.  With
+        ``tc.prefetch`` the host side of chunk i+1 (row slicing +
+        shard_batch_put + key derivation) rides a background thread behind
+        chunk i's async dispatch (_OneAhead) — bit-exact either way."""
         tc = self.tc
         eng = self._engine()
         wd_fired0 = watchdog.fired if watchdog is not None else 0
@@ -575,6 +585,7 @@ class ContrastiveTrainer:
         next_log = ((start_step // tc.log_every) + 1) * tc.log_every
         pending: list[tuple] = []   # (ys device array, live bool mask)
         n_chunks = 0
+        skipped = 0
         t0 = time.time()
 
         def flush():
@@ -640,11 +651,14 @@ class ContrastiveTrainer:
                          enabled=tc.prefetch, depth=tc.prefetch_depth)
         for (_, _, hi), (stacked, keys, live) in pipe:
             n_chunks += 1
+            n_live = int(live.sum())
+            skipped += chunk_len - n_live
             if watchdog is not None:
                 watchdog.step_start()
-            # the dispatch only: the chunk runs on after the span closes
-            with telemetry.span("fit.chunk", computed=chunk_len,
-                                live=int(live.sum())):
+            # the dispatch only: the chunk runs on after the span closes;
+            # the device runs the step body for the live steps alone
+            with telemetry.span("fit.chunk", computed=n_live, live=n_live,
+                                skipped=chunk_len - n_live):
                 state, ys = eng.scan(state, stacked, keys,
                                      jnp.asarray(live))
             pending.append((ys, live))
@@ -711,6 +725,7 @@ class ContrastiveTrainer:
             "checkpoint_saves": saves,
             "scan_chunks": n_chunks,
             "chunk_len": chunk_len,
+            "skipped_steps": skipped,
             "prefetch": pipe.enabled,
             "prefetch_stage_s": pipe.stage_s,
             "prefetch_wait_s": pipe.wait_s,

@@ -184,8 +184,9 @@ def test_results_identical_with_recorder_on_and_off(runs):
 
 @pytest.mark.parametrize("prefetch", [True, False])
 def test_fit_chunk_counts_match_the_schedule(tmp_path, prefetch):
-    """fit.chunk counts every computed scan step and the live ones among
-    them: 6 steps in chunks of 4 pad the last chunk with dead steps."""
+    """fit.chunk counts the step bodies the device runs (the live steps)
+    and the scan iterations its branch passed through: 6 steps in chunks of
+    4 pad the last chunk with dead steps, which compute nothing."""
     graphs = [build_kernel_graph(make_kernel(
         f"k{i}", "gemm", {"M": 128 * (i % 3 + 1), "N": 128, "K": 128}, i,
         seed=i).trace(cap_warps=2, cap_instr=48)) for i in range(6)]
@@ -200,10 +201,12 @@ def test_fit_chunk_counts_match_the_schedule(tmp_path, prefetch):
     stages = [r for r in telemetry.spans() if r.name == "fit.stage"]
     telemetry.clear()
     assert len(chunks) == info["scan_chunks"] == len(stages)
-    assert sum(r.counts["computed"] for r in chunks) == \
-        info["scan_chunks"] * info["chunk_len"]
-    assert sum(r.counts["live"] for r in chunks) == len(info["history"]) == 6
-    assert info["scan_chunks"] * info["chunk_len"] > 6
+    computed = sum(r.counts["computed"] for r in chunks)
+    skipped = sum(r.counts["skipped"] for r in chunks)
+    assert computed == sum(r.counts["live"] for r in chunks) == \
+        len(info["history"]) == 6
+    assert computed + skipped == info["scan_chunks"] * info["chunk_len"]
+    assert info["skipped_steps"] == skipped > 0
 
 
 def test_span_outside_a_session_measures_only_when_timed():

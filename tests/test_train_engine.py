@@ -19,6 +19,7 @@ from repro.core.train import (
     packed_loss,
 )
 from repro.distributed.sharding import MeshRules, constrain_batch
+from repro.optim import adamw_init
 from repro.tracing.templates import make_kernel
 
 
@@ -108,6 +109,81 @@ def test_scan_host_syncs_bounded_by_log_every():
         RGCNConfig(), _tc(engine="python")).fit(GRAPHS)
     assert info_py["host_syncs"] >= 8  # one per step (+ val)
     assert info["engine"] == "scan" and info_py["engine"] == "python"
+
+
+def test_dead_steps_match_a_schedule_without_padding():
+    """6 steps in chunks of 4 leave dead steps; chunks of 1 leave none.
+    The branch that passes a dead step through must leave the trajectory
+    exactly that of the unpadded fit (bit-exact on the CPU)."""
+    p_pad, i_pad = ContrastiveTrainer(
+        RGCNConfig(), _tc(steps=6, scan_chunk=4)).fit(GRAPHS)
+    p_one, i_one = ContrastiveTrainer(
+        RGCNConfig(), _tc(steps=6, scan_chunk=1)).fit(GRAPHS)
+    assert i_pad["skipped_steps"] > 0 and i_one["skipped_steps"] == 0
+    assert i_pad["skipped_steps"] == \
+        i_pad["scan_chunks"] * i_pad["chunk_len"] - 6
+    for a, b in zip(_leaves(p_pad), _leaves(p_one)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    assert i_pad["history"] == i_one["history"]
+    assert i_pad["val_loss"] == i_one["val_loss"]
+
+
+def _chunk_args(live):
+    """(state, stacked rows, keys, live) for one chunk of len(live) steps,
+    every row the same packed batch."""
+    tr = ContrastiveTrainer(RGCNConfig(), _tc())
+    state = adamw_init(rgcn_mod.init_rgcn(jax.random.PRNGKey(0), tr.rc),
+                       tr._opt)
+    packed, _ = pack_graphs(GRAPHS[:4])
+    stacked = {k: jnp.asarray(np.stack([v] * len(live)))
+               for k, v in packed.items()}
+    keys = jax.vmap(lambda i: jax.random.fold_in(jax.random.PRNGKey(0), i))(
+        jnp.arange(len(live)))
+    return tr._engine().scan, (state, stacked, keys, jnp.asarray(live))
+
+
+def test_all_dead_chunk_passes_state_through():
+    """A chunk with no live step returns the carried state unchanged and a
+    zero metrics row per step."""
+    scan, (state, stacked, keys, live) = _chunk_args([False, False])
+    before = [np.asarray(x).copy() for x in _leaves(state)]
+    out, ys = scan(state, stacked, keys, live)   # donates `state`
+    for a, b in zip(before, _leaves(out)):
+        assert np.array_equal(a, np.asarray(b))
+    np.testing.assert_array_equal(np.asarray(ys),
+                                  np.zeros((2, len(METRIC_KEYS))))
+
+
+def _primitives(eqns) -> set:
+    """Names of every primitive in ``eqns`` and their sub-jaxprs."""
+    out = set()
+    for e in eqns:
+        out.add(e.primitive.name)
+        for v in e.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    out |= _primitives(sub.eqns)
+    return out
+
+
+def test_chunk_branches_past_dead_steps():
+    """The scan body is one conditional on `live`: the step's products sit
+    in one branch only, and none runs outside it, so a dead step computes
+    nothing (a compute-then-discard body would fail this)."""
+    scan, args = _chunk_args([True, False])
+    assert "stablehlo.case" in scan.lower(*args).as_text()
+    jaxpr = jax.make_jaxpr(scan)(*args).jaxpr
+    scan_eqn, = [e for e in jaxpr.eqns[0].params["jaxpr"].jaxpr.eqns
+                 if e.primitive.name == "scan"]
+    body = scan_eqn.params["jaxpr"].jaxpr
+    conds = [e for e in body.eqns if e.primitive.name == "cond"]
+    assert len(conds) == 1
+    outside = [e for e in body.eqns if e is not conds[0]]
+    assert "dot_general" not in _primitives(outside)
+    branches = [_primitives(b.jaxpr.eqns)
+                for b in conds[0].params["branches"]]
+    assert sorted("dot_general" in b for b in branches) == [False, True]
 
 
 def test_epoch_plan_covers_steps_in_order():
