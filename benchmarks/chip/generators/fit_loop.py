@@ -7,16 +7,21 @@ programs, the trace window, the pool (how many graphs, drawn with which
 sample seed from all the programs' invocations), the encoder widths and
 the training settings.
 
-The fit's own seed is one of the configuration's ``schedule.seeds``, picked
-by the run's seed: each gives a different fit with the same device work.
-Set-up builds the pool and runs the first steps of that fit, as many as
-reach every packed shape of its schedule (``warm_steps``): that compiles
-every executable the whole fit runs, so nothing compiles inside the window.
+Each fit's own seed is one of the configuration's ``schedule.seeds``, all
+of which do the same device work on different data (``schedule_work``):
+the window's first fit takes the one the run's seed picks, and each later
+fit the next in the list, so that every run spreads its fits over the
+same schedules.  Set-up builds the pool and runs the first steps of one
+listed fit, the one that reaches every packed shape in the fewest steps
+(``warm_seed``, ``warm_steps``): the listed fits share their shapes, so
+that compiles every executable the window runs, and nothing compiles
+inside it.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import json
 import time
 
 import numpy as np
@@ -68,19 +73,27 @@ def packed_sizes(graphs: list, cfg: dict) -> list:
     return out
 
 
-def _step_shapes(sizes: list, seed: int, cfg: dict) -> list:
-    """The packed shape of each step of the fit's schedule from ``seed``.
-    ``sizes``: (nodes, edges, warps) of each graph after truncation."""
+def _packed_shapes(sizes: list, batches) -> list:
+    """(nodes, edges, warps) buckets of each batch of graph indices packed
+    as one.  ``sizes``: (nodes, edges, warps) of each graph after
+    truncation."""
+    tot = np.asarray(sizes)[np.asarray(batches)].sum(axis=1)
+    return [(reference.pow2_at_least(n, reference.NODE_FLOOR),
+             reference.pow2_at_least(max(e, 1), reference.EDGE_FLOOR),
+             reference.pow2_at_least(max(w, 1), reference.WARP_FLOOR))
+            for n, e, w in tot.tolist()]
+
+
+def _split(sizes: list, seed: int, cfg: dict) -> tuple:
+    """(training, held-out, per-step selections) of the fit from ``seed``."""
     t = cfg["train"]
-    _, _, sel = reference.split_and_selections(
+    return reference.split_and_selections(
         len(sizes), t["steps"], t["batch_size"], t["val_fraction"], seed)
-    keys = []
-    for s in sel:
-        n, e, w = (sum(sizes[j][k] for j in s) for k in range(3))
-        keys.append((reference.pow2_at_least(n, reference.NODE_FLOOR),
-                     reference.pow2_at_least(max(e, 1), reference.EDGE_FLOOR),
-                     reference.pow2_at_least(max(w, 1), reference.WARP_FLOOR)))
-    return keys
+
+
+def _step_shapes(sizes: list, seed: int, cfg: dict) -> list:
+    """The packed shape of each step of the fit's schedule from ``seed``."""
+    return _packed_shapes(sizes, _split(sizes, seed, cfg)[2])
 
 
 def _chunk_len(steps: int, cfg: dict) -> int:
@@ -99,12 +112,22 @@ def warm_steps(sizes: list, seed: int, cfg: dict) -> int:
     return n
 
 
+def warm_seed(sizes: list, cfg: dict) -> int:
+    """The listed schedule seed whose fit reaches every packed shape in the
+    fewest leading steps (the first such in the list)."""
+    seeds = cfg["schedule"]["seeds"]
+    return min(seeds, key=lambda s: warm_steps(sizes, s, cfg))
+
+
 def schedule_chunks(sizes: list, seed: int, cfg: dict) -> list:
     """The device work of a fit's schedule from ``seed``: how many scan
     chunks run at each packed shape.  The trainer groups consecutive steps
     of one shape into segments and pads each segment's last chunk to the
     chunk length, so the order of the shapes sets the work."""
-    keys = _step_shapes(sizes, seed, cfg)
+    return _chunk_counts(_step_shapes(sizes, seed, cfg), cfg)
+
+
+def _chunk_counts(keys: list, cfg: dict) -> list:
     chunk = _chunk_len(len(keys), cfg)
     out: dict = {}
     i = 0
@@ -117,14 +140,46 @@ def schedule_chunks(sizes: list, seed: int, cfg: dict) -> list:
     return sorted([list(k), v] for k, v in out.items())
 
 
+def schedule_work(sizes: list, seed: int, cfg: dict) -> dict:
+    """All the device work of a fit that its seed sets, as the
+    configuration's ``schedule`` states it: the scan chunks at each packed
+    shape (``schedule_chunks``), the live steps at each, and the packed
+    shape of the held-out batch that each fit's eval runs on."""
+    _, held_out, sel = _split(sizes, seed, cfg)
+    keys = _packed_shapes(sizes, sel)
+    live: dict = {}
+    for k in keys:
+        live[k] = live.get(k, 0) + 1
+    return {"chunks": _chunk_counts(keys, cfg),
+            "live": sorted([list(k), v] for k, v in live.items()),
+            "eval": list(_packed_shapes(sizes, [held_out])[0])}
+
+
+def derive_schedule(sizes: list, cfg: dict, seeds) -> tuple:
+    """The commonest ``schedule_work`` among ``seeds`` and the seeds that
+    share it, in order; a tie goes to the fewer scan chunks, then to the
+    work seen first."""
+    groups: dict = {}
+    for seed in seeds:
+        w = schedule_work(sizes, seed, cfg)
+        groups.setdefault(json.dumps(w, sort_keys=True), []).append(seed)
+    best = max(groups.items(), key=lambda kv: (
+        len(kv[1]), -sum(c for _, c in json.loads(kv[0])["chunks"])))
+    return json.loads(best[0]), best[1]
+
+
 class Generator:
-    span_names = ("fit",)
+    #: what names the window's idle gaps: the program's own host work in a
+    #: fit (``repro.telemetry``).  A gap outside them is the held-out
+    #: eval's packing, the next fit's initialisation or the loop itself.
+    span_names = ("fit.plan_epoch", "fit.wait", "fit.pack", "fit.keys")
 
     def __init__(self, cell, seed: int, spans, rgcn_overrides=None):
         self.cell, self.cfg, self.seed, self.spans = (
             cell, cell.config, seed, spans)
         self.rgcn_overrides = rgcn_overrides
         self.first = None          # (params, info) of the window's first fit
+        self.fit_seeds: list = []  # schedule seed of each completed fit
 
     def setup(self, warm: bool = True, pool: list | None = None) -> None:
         """``pool``: graphs already built for this configuration (the
@@ -134,34 +189,39 @@ class Generator:
         self.graphs = pool if pool is not None else build_pool(self.cfg)
         self.rc = rgcn_config(self.cfg, self.rgcn_overrides)
         seeds = self.cfg["schedule"]["seeds"]
-        self.tc = train_config(self.cfg, seeds[program_seed(self.seed)
-                                               % len(seeds)])
+        start = program_seed(self.seed) % len(seeds)
+        self.tcs = [train_config(self.cfg, s)
+                    for s in seeds[start:] + seeds[:start]]
+        self.tc = self.tcs[0]      # the window's first fit, the one checked
         self.trainer_cls = ContrastiveTrainer
         if warm:
-            n = warm_steps(packed_sizes(self.graphs, self.cfg), self.tc.seed,
-                           self.cfg)
+            sizes = packed_sizes(self.graphs, self.cfg)
+            w = warm_seed(sizes, self.cfg)
             self.trainer_cls(self.rc, dataclasses.replace(
-                self.tc, steps=n)).fit(self.graphs)
+                train_config(self.cfg, w),
+                steps=warm_steps(sizes, w, self.cfg))).fit(self.graphs)
 
     def run_window(self, seconds: float) -> dict:
         attempted = failed = steps = 0
         self.errors: list = []
         t0 = time.perf_counter()
         while time.perf_counter() - t0 < seconds:
+            tc = self.tcs[attempted % len(self.tcs)]
             attempted += 1
             try:
                 with self.spans("fit"):
-                    params, info = self.trainer_cls(self.rc, self.tc).fit(
+                    params, info = self.trainer_cls(self.rc, tc).fit(
                         self.graphs)
             except Exception as e:  # counted, and the run is not correct
                 failed += 1
                 self.errors.append(repr(e))
                 continue
-            if self.first is None:
+            if self.first is None and tc is self.tc:
                 self.first = (params, info)
+            self.fit_seeds.append(tc.seed)
             steps += len(info["history"])
         self.elapsed = time.perf_counter() - t0
-        self.steps, self.fits = steps, attempted - failed
+        self.steps = steps
         return {"attempted": attempted, "failed": failed,
                 "elapsed_s": self.elapsed, "steps": steps}
 
@@ -195,19 +255,20 @@ class Generator:
     def layer_inputs(self) -> dict:
         """Operations of the window's fits: every step's batch (both views,
         forward and backward) and the held-out forward of each fit."""
-        t = self.cfg["train"]
         rc = self.cfg["rgcn"]
         sizes = self._sizes()
-        _, val_idx, sel = reference.split_and_selections(
-            len(sizes), t["steps"], t["batch_size"], t["val_fraction"],
-            self.tc.seed)
-        per_fit = sum(counts.train_step_flops([sizes[j] for j in s], rc)
-                      for s in sel)
-        vn = sum(sizes[j][0] for j in val_idx)
-        ve = sum(sizes[j][1] for j in val_idx)
-        per_fit += 2 * (counts.encoder_flops(vn, ve, rc)
-                        + counts.projection_flops(len(val_idx), rc))
-        return {"work_flops": per_fit * self.fits,
+
+        def per_fit(seed):
+            _, val_idx, sel = _split(sizes, seed, self.cfg)
+            vn = sum(sizes[j][0] for j in val_idx)
+            ve = sum(sizes[j][1] for j in val_idx)
+            return (sum(counts.train_step_flops([sizes[j] for j in s], rc)
+                        for s in sel)
+                    + 2 * (counts.encoder_flops(vn, ve, rc)
+                           + counts.projection_flops(len(val_idx), rc)))
+
+        flops = {s: per_fit(s) for s in set(self.fit_seeds)}
+        return {"work_flops": sum(flops[s] for s in self.fit_seeds),
                 "elapsed_s": self.elapsed}
 
     def free(self) -> None:

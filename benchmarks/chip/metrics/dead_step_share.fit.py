@@ -1,6 +1,8 @@
 """Share of the scan steps the device computed that were padding: 1 - live
-steps over computed steps, summed over the window's ``fit.chunk`` spans
-(each chunk computes its whole length; masked steps are discarded)."""
+steps over computed steps, summed over the window's ``fit.chunk`` spans.
+A chunk whose body branches past its padded steps counts them as
+``skipped``, not ``computed``, and reads 0; one that computes them and
+discards the result reads their share."""
 
 from benchmarks.chip.program_spans import recorded
 

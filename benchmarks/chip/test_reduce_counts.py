@@ -140,27 +140,52 @@ def test_reference_pack_matches_program_pack():
         np.testing.assert_array_equal(got[k], v, err_msg=k)
 
 
-def test_schedule_seeds_share_their_device_work():
-    """Every fit seed the zoo-decode configuration lists runs the same
-    number of scan chunks at each packed shape, and set-up warms all of
-    them in about the same, smaller number of chunks."""
+@pytest.fixture(scope="module")
+def zoo():
+    """The zoo-decode configuration and its pool's packed sizes."""
     from benchmarks.chip.generators import fit_loop
 
     cfg = harness.load_json(os.path.join(
         harness.BENCH_DIR, "configs", "zoo-decode.json"))
-    sizes = fit_loop.packed_sizes(fit_loop.build_pool(cfg), cfg)
-    want = cfg["schedule"]["chunks"]
-    warm_chunks = []
+    return cfg, fit_loop.packed_sizes(fit_loop.build_pool(cfg), cfg)
+
+
+def _stated_work(cfg: dict, work: dict) -> dict:
+    return {k: cfg["schedule"][k] for k in work}
+
+
+def test_schedule_seeds_share_their_device_work(zoo):
+    """Every fit seed the zoo-decode configuration lists does the work the
+    configuration states (``fit_loop.schedule_work``: scan chunks and live
+    steps at each packed shape, the held-out batch's shape), an unlisted
+    seed does not, and set-up's warm fit reaches every shape of them, at
+    the fit's chunk length, in fewer chunks than a fit."""
+    from benchmarks.chip.generators import fit_loop
+
+    cfg, sizes = zoo
+    want = fit_loop.schedule_work(sizes, cfg["schedule"]["seeds"][0], cfg)
+    assert want == _stated_work(cfg, want)
+    assert sum(n for _, n in want["live"]) == cfg["train"]["steps"]
     for seed in cfg["schedule"]["seeds"]:
-        assert fit_loop.schedule_chunks(sizes, seed, cfg) == want, seed
-        # set-up's warm fit reaches every shape of the fit, at its chunk
-        # length, in fewer chunks
-        n = fit_loop.warm_steps(sizes, seed, cfg)
-        warm = dict(cfg, train=dict(cfg["train"], steps=n))
-        got = fit_loop.schedule_chunks(sizes, seed, warm)
-        assert [k for k, _ in got] == [k for k, _ in want], seed
-        assert fit_loop._chunk_len(n, cfg) == cfg["train"]["scan_chunk"]
-        warm_chunks.append(sum(c for _, c in got))
-    assert max(warm_chunks) - min(warm_chunks) <= 2
-    assert max(warm_chunks) < sum(c for _, c in want)
-    assert fit_loop.schedule_chunks(sizes, 0, cfg) != want
+        assert fit_loop.schedule_work(sizes, seed, cfg) == want, seed
+    unlisted = next(s for s in range(20) if s not in cfg["schedule"]["seeds"])
+    assert fit_loop.schedule_work(sizes, unlisted, cfg) != want
+    w = fit_loop.warm_seed(sizes, cfg)
+    n = fit_loop.warm_steps(sizes, w, cfg)
+    warm = fit_loop.schedule_chunks(
+        sizes, w, dict(cfg, train=dict(cfg["train"], steps=n)))
+    assert [k for k, _ in warm] == [k for k, _ in want["chunks"]]
+    assert fit_loop._chunk_len(n, cfg) == cfg["train"]["scan_chunk"]
+    assert sum(c for _, c in warm) < sum(c for _, c in want["chunks"])
+
+
+def test_schedule_seeds_follow_from_the_rule(zoo):
+    """The listed seeds are exactly those that the configuration's rule
+    (``fit_loop.derive_schedule``) picks from its search range."""
+    from benchmarks.chip.generators import fit_loop
+
+    cfg, sizes = zoo
+    lo, hi = cfg["schedule"]["search"]
+    work, seeds = fit_loop.derive_schedule(sizes, cfg, range(lo, hi))
+    assert seeds == cfg["schedule"]["seeds"]
+    assert work == _stated_work(cfg, work)

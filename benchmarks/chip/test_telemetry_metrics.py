@@ -124,21 +124,44 @@ def _traced_window(bench, cell, tmp_path, seconds=0.2):
 
 
 def test_fit_window_reads_the_schedule_dead_share(bench, tmp_path):
-    """dead_step_share.fit equals the padding the fit's schedule implies,
-    counted offline from the seed (``fit_loop.schedule_chunks``)."""
+    """The window's ``fit.chunk`` spans account for the fit's schedule,
+    counted offline from the seed (``fit_loop.schedule_chunks``): each fit
+    computes its live steps and passes the schedule's padding through the
+    branch (Σ ``skipped``), so ``dead_step_share.fit`` reads 0."""
     from benchmarks.chip.generators import fit_loop
 
     gen, window, view = _traced_window(bench, "zoo-fit", tmp_path)
     try:
         cfg = gen.cfg
-        chunks = fit_loop.schedule_chunks(
-            fit_loop.packed_sizes(gen.graphs, cfg), gen.tc.seed, cfg)
-        computed = sum(c for _, c in chunks) * fit_loop._chunk_len(
-            cfg["train"]["steps"], cfg)
-        want = 100.0 * (1.0 - cfg["train"]["steps"] / computed)
-        assert harness.load_reader("dead_step_share.fit")(view) == \
-            pytest.approx(want)
+        sizes = fit_loop.packed_sizes(gen.graphs, cfg)
+        steps = cfg["train"]["steps"]
+        slots = [sum(c for _, c in fit_loop.schedule_chunks(sizes, s, cfg))
+                 * fit_loop._chunk_len(steps, cfg) for s in gen.fit_seeds]
+        assert len(slots) == window["attempted"] - window["failed"] >= 1
+        assert sum(slots) > len(slots) * steps  # padding to pass
+        recs = [r.counts for r in telemetry.spans() if r.name == "fit.chunk"]
+        assert sum(c["live"] for c in recs) == len(slots) * steps
+        assert sum(c["computed"] for c in recs) == len(slots) * steps
+        assert sum(c["skipped"] for c in recs) == \
+            sum(slots) - len(slots) * steps
+        assert harness.load_reader("dead_step_share.fit")(view) == 0.0
         assert 0.0 < harness.load_reader("stage_share.fit")(view) < 100.0
+    finally:
+        telemetry.clear()
+
+
+def test_fit_gap_names_are_spans_the_program_records(bench, tmp_path):
+    """The spans that name zoo-fit's idle gaps (``span_names``, handed to
+    ``reduce_trace``) are spans a traced fit records, each with time."""
+    from benchmarks.chip.generators import fit_loop
+
+    _, window, _ = _traced_window(bench, "zoo-fit", tmp_path)
+    try:
+        took = {}
+        for r in telemetry.spans():
+            took[r.name] = took.get(r.name, 0) + r.end_ns - r.start_ns
+        for name in fit_loop.Generator.span_names:
+            assert took.get(name, 0) > 0, name
     finally:
         telemetry.clear()
 
