@@ -165,6 +165,38 @@ class ModelConfig:
         return dataclasses.replace(self, **kw)
 
 
+@dataclass(frozen=True)
+class LatentMoEConfig:
+    """A DeepSeek-V3-style decoder as the trace path reads it (arXiv
+    2412.19437 §2): latent attention (MLA) in every layer, ``first_dense``
+    leading layers with a dense SwiGLU of width ``d_ff``, then routed
+    experts of width ``expert_d_ff`` chosen by group-limited routing beside
+    ``num_shared_experts`` always-on experts, and ``mtp_depth``
+    multi-token-prediction modules after the main layers.  Only
+    `repro.workloads.modelzoo` builds it (a kernel launch stream): the
+    ``repro.models`` stack has no MLA, so these configs stay out of
+    ``repro.configs.ARCHS``."""
+    arch_id: str
+    num_layers: int
+    d_model: int
+    num_heads: int
+    vocab_size: int
+    d_ff: int                    # dense layers' SwiGLU width
+    first_dense: int             # leading dense layers
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    num_experts: int             # routed experts
+    top_k: int                   # routed experts per token
+    expert_d_ff: int
+    num_shared_experts: int
+    n_group: int                 # expert groups of the router
+    topk_group: int              # groups a token's experts may come from
+    mtp_depth: int
+
+
 # ---------------------------------------------------------------------------
 # Shapes (assigned LM-family set)
 # ---------------------------------------------------------------------------

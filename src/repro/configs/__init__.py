@@ -9,10 +9,11 @@ from __future__ import annotations
 
 import dataclasses
 
-from repro.config import ModelConfig
+from repro.config import LatentMoEConfig, ModelConfig
 from repro.utils.registry import Registry
 
 from repro.configs import (
+    deepseek_v3,
     jamba_v0_1_52b,
     musicgen_medium,
     granite_3_2b,
@@ -40,6 +41,13 @@ for _mod in (
     paligemma_3b,
 ):
     ARCHS.add(_mod.CONFIG.arch_id, _mod.CONFIG)
+
+
+#: architectures that only the trace path builds (`LatentMoEConfig`:
+#: repro.workloads.modelzoo walks them into launch streams; repro.models
+#: has no layer for them, so they are not in ARCHS)
+TRACE_ARCHS: Registry[LatentMoEConfig] = Registry("trace-only architecture")
+TRACE_ARCHS.add(deepseek_v3.CONFIG.arch_id, deepseek_v3.CONFIG)
 
 
 def get_arch(arch_id: str) -> ModelConfig:

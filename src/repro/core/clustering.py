@@ -626,8 +626,10 @@ def sweep_cluster_stack(
                 init_idx[row, :k_up] = _kmeanspp_init(x, k_up, seeds[i])
 
     shards = _effective_shards(B, data_shards)
+    # padded: the points the dispatch computes, the pow2 batch and points
+    # buckets included
     with telemetry.span("plan.sweep", points=sum(len(xs[i]) for i in todo),
-                        k_max=k_max):
+                        padded=B * n_pad, k_max=k_max):
         fn = _sweep_fn(B, n_pad, d, k_max, iters, use_pallas, blk, shards)
         ENGINE_STATS["dispatches"] += 1
         if shards > 1:

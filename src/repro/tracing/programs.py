@@ -283,6 +283,104 @@ def _lm_layer_kernels(prefix, d_model, d_ff, n_heads, seq_len, decode,
     return ks, s
 
 
+def mm_kernel(name, n, k, decode, seq, seed, batch=1, m=1):
+    """One matrix-product launch: ``batch`` products of (m x k) @ (k x n).
+    A decode step's products have one row per batch element (``m`` is 1)
+    and launch as matvecs; a prefill's as GEMMs."""
+    p = {"n": n, "m": k} if decode else {"M": m, "N": n, "K": k}
+    if batch > 1:
+        p["batch"] = batch
+    return make_kernel(name, "gemv" if decode else "gemm", p, seq, seed)
+
+
+def _mla_layer_kernels(prefix, c, ctx, decode, seq_start, seed, routed):
+    """One DeepSeek-V3 decoder layer (arXiv 2412.19437 §2.1-2.2) in the
+    order a GPU launches it, at batch 1: ``ctx`` is the decode step's
+    context (attention covers ``ctx`` cached positions) or the prefill's
+    length (``ctx`` tokens, causal).
+
+    Attention is MLA.  Decode takes the absorbed form: q down-projection and
+    norm, q up-projection, kv down-projection to the latent row
+    (``kv_lora_rank`` + ``qk_rope_head_dim``) and its norm, the decoupled
+    RoPE (which also appends the row to the latent cache), ``W_UK`` folded
+    into each head's query, one fused attention in which every head reads
+    the shared latent rows, ``W_UV`` per head, the O projection.  Prefill
+    takes the naive form: ``kv_b_proj`` up-projects the latent rows to each
+    head's keys and values, and the fused attention streams them per head.
+
+    The FFN is a dense SwiGLU (gate and up in one product), or with
+    ``routed`` the router, the group-limited top-k, the routed experts'
+    SwiGLU (a decode token's ``top_k`` experts as one batched launch; a
+    prefill's ``num_experts`` grouped products of the balanced share,
+    ceil(ctx * top_k / num_experts) tokens each), the shared experts'
+    SwiGLU and the weighted combine."""
+    ks = []
+    s = seq_start
+    T = 1 if decode else ctx
+    D, H = c.d_model, c.num_heads
+    qk = c.qk_nope_head_dim + c.qk_rope_head_dim
+
+    def add(role, template, params):
+        nonlocal s
+        ks.append(make_kernel(f"{prefix}_{role}", template, params, s, seed))
+        s += 1
+
+    def mm(role, n, k, batch=1, m=T):
+        nonlocal s
+        ks.append(mm_kernel(f"{prefix}_{role}", n, k, decode, s, seed,
+                            batch=batch, m=m))
+        s += 1
+
+    def norm(role, width):
+        add(role, "softmax", {"rows": T, "cols": width})
+
+    def swiglu(role, width, batch=1, m=T):
+        mm(f"{role}_gate_up", 2 * width, D, batch, m)
+        add(f"{role}_silu_mul", "elementwise",
+            {"n": batch * m * width, "nops": 3, "iters": 4})
+        mm(f"{role}_down", D, width, batch, m)
+
+    norm("input_rmsnorm", D)
+    mm("q_a_proj", c.q_lora_rank, D)
+    norm("q_a_rmsnorm", c.q_lora_rank)
+    mm("q_b_proj", H * qk, c.q_lora_rank)
+    mm("kv_a_proj", c.kv_lora_rank + c.qk_rope_head_dim, D)
+    norm("kv_a_rmsnorm", c.kv_lora_rank)
+    add("rope", "elementwise",
+        {"n": T * (H + 1) * c.qk_rope_head_dim, "nops": 4, "iters": 4})
+    if decode:
+        mm("w_uk_absorb", c.kv_lora_rank, c.qk_nope_head_dim, batch=H)
+        add("mla_attention", "mla_attention",
+            {"heads": H, "q_len": T, "kv_len": ctx,
+             "qk_dim": c.kv_lora_rank + c.qk_rope_head_dim,
+             "v_dim": c.kv_lora_rank, "kv_heads": 1})
+        mm("w_uv", c.v_head_dim, c.kv_lora_rank, batch=H)
+    else:
+        mm("kv_b_proj", H * (c.qk_nope_head_dim + c.v_head_dim),
+           c.kv_lora_rank)
+        add("mla_attention", "mla_attention",
+            {"heads": H, "q_len": T, "kv_len": ctx, "qk_dim": qk,
+             "v_dim": c.v_head_dim, "kv_heads": H})
+    mm("o_proj", D, H * c.v_head_dim)
+    norm("post_attention_rmsnorm", D)
+    if not routed:
+        swiglu("mlp", c.d_ff)
+        return ks, s
+    E, k = c.num_experts, c.top_k
+    mm("router", E, D)
+    add("grouped_topk", "grouped_topk",
+        {"rows": T, "experts": E, "groups": c.n_group,
+         "topk_group": c.topk_group, "top_k": k})
+    if decode:
+        swiglu("experts", c.expert_d_ff, batch=k, m=1)
+    else:
+        swiglu("experts", c.expert_d_ff, batch=E, m=-(-T * k // E))
+    swiglu("shared_expert", c.expert_d_ff * c.num_shared_experts)
+    add("moe_combine", "elementwise",
+        {"n": T * D, "nops": k + 1, "iters": 4})
+    return ks, s
+
+
 def _build_llm(name, layers, d_model, d_ff, n_heads, steps, seq_len, seed,
                platform_sensitive=False):
     ks = []
@@ -300,8 +398,6 @@ def _build_llm(name, layers, d_model, d_ff, n_heads, steps, seq_len, seed,
                               {"n": 50_000, "m": d_model} if decode
                               else {"M": max(seq_len, 64), "N": 50_000, "K": d_model},
                               s, seed)); s += 1
-    for k in ks:
-        k.seq = ks.index(k) if False else k.seq  # seq already assigned
     # re-sequence deterministically
     for i, k in enumerate(ks):
         k.seq = i

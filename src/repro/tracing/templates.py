@@ -51,20 +51,23 @@ def gemm_body(params):
 
 
 def gemm_stats(params, platform):
+    # ``batch``: independent products of this shape in one launch (a
+    # batched or grouped GEMM over heads or experts)
     M, N, K = params["M"], params["N"], params["K"]
+    b = params.get("batch", 1)
     fp16 = params.get("fp16", False)
     body, n_iter, _ = gemm_body(params)
-    ctas = max(1, (M // 64) * (N // 64))
+    ctas = max(1, (M // 64) * (N // 64)) * b
     elt = 2 if fp16 else 4
     # tiled-GEMM traffic: A rereads once per 128-wide N tile, B per M tile
     tile = 128
     bytes_acc = elt * (
         M * K * max(1, N // tile) + K * N * max(1, M // tile) + M * N
-    )
-    ws = (M * K + K * N + M * N) * elt
+    ) * b
+    ws = (M * K + K * N + M * N) * elt * b
     return make_stats(
         body_class_counts=_count_classes(body), n_iter=n_iter, ctas=ctas,
-        threads_per_cta=256, flops_total=2.0 * M * N * K,
+        threads_per_cta=256, flops_total=2.0 * M * N * K * b,
         bytes_accessed=max(bytes_acc, ws), working_set=ws,
         pattern="coalesced", regs=96 if fp16 else 64, smem=32768, ilp=4.0,
     )
@@ -369,19 +372,144 @@ def gemv_body(params):
 
 
 def gemv_stats(params, platform):
+    # ``batch``: independent matvecs of this shape in one launch (per head,
+    # or per routed expert of a decode token)
     n, m = params["n"], params["m"]
+    b = params.get("batch", 1)
     body, n_iter, _ = gemv_body(params)
-    ctas = max(1, n // 64)
+    ctas = max(1, n // 64) * b
     ilp = 1.0 if params.get("acc_regs", 2) == 1 else 6.0
     return make_stats(
         body_class_counts=_count_classes(body), n_iter=n_iter, ctas=ctas,
-        threads_per_cta=256, flops_total=2.0 * n * m,
-        bytes_accessed=4.0 * n * m + 8.0 * m, working_set=4.0 * n * m,
+        threads_per_cta=256, flops_total=2.0 * n * m * b,
+        bytes_accessed=(4.0 * n * m + 8.0 * m) * b, working_set=4.0 * n * m * b,
         pattern="coalesced", regs=32, ilp=ilp,
     )
 
 
 TEMPLATES.add("gemv", (gemv_body, gemv_stats))
+
+
+# ---------------------------------------------------------------------------
+# Fused attention over cached rows (FlashAttention / FlashMLA style)
+# ---------------------------------------------------------------------------
+
+
+def mla_attention_body(params):
+    """Scores, online softmax and the value product of ``heads`` query heads
+    in one loop over blocks of ``kv_len`` cached positions.  With
+    ``kv_heads == 1`` every head reads ONE shared latent row per position
+    (MLA's absorbed decode: the keys are the whole ``qk_dim``-wide row, the
+    values its first ``v_dim`` columns), so the row is loaded once into
+    shared memory and read twice; with ``kv_heads == heads`` (MLA's naive
+    form) each head streams its own key and value rows."""
+    shared = params["kv_heads"] == 1
+    body = [I("LDG", (10,), (2,), mem={"kind": "load", "width": 16, "stride_iter": params["qk_dim"] * 4, "base": 0x10000000, "pattern": "coalesced"})]
+    if not shared:
+        body.append(I("LDG", (11,), (3,), mem={"kind": "load", "width": 16, "stride_iter": params["v_dim"] * 4, "base": 0x20000000, "pattern": "coalesced"}))
+    body += [I("STS", (), (10,))]
+    if not shared:
+        body.append(I("STS", (), (11,)))
+    body += [I("BAR", (), ()), I("LDS", (12,), ())]
+    for r in range(4 if shared else 2):
+        body.append(I("HMMA", (20 + r,), (12, 4, 20 + r)))
+    body += [
+        I("FMUL", (14,), (20,)),
+        I("FSETP", (), (14, 15)),
+        I("MUFU", (16,), (14,)),
+        I("FADD", (17,), (16, 17)),
+        I("SHFL", (18,), (17,)),
+        I("LDS", (13,), ()),
+    ]
+    for r in range(4 if shared else 2):
+        body.append(I("HMMA", (24 + r,), (16, 13, 24 + r)))
+    body += [I("BAR", (), ()), I("IADD3", (2,), (2,)), I("ISETP", (), (2,)), I("BRA", (), ())]
+    return body, max(1, params["kv_len"] // 64), {"warps_per_cta": 8}
+
+
+def mla_attention_stats(params, platform):
+    H, q, kv = params["heads"], params["q_len"], params["kv_len"]
+    qk, v, kvh = params["qk_dim"], params["v_dim"], params["kv_heads"]
+    body, n_iter, _ = mla_attention_body(params)
+    causal = 0.5 if q == kv and q > 1 else 1.0
+    # the shared latent row holds the values too
+    kv_bytes = 4.0 * kvh * kv * (qk if kvh == 1 else qk + v)
+    q_bytes = 4.0 * H * q * (qk + v)
+    # one CTA per 64 query rows; a single-query decode splits the cache
+    # into 1,024-position chunks (split-KV) to fill the machine
+    ctas = max(1, H * q // 64) * (max(1, kv // 1024) if q == 1 else 1)
+    return make_stats(
+        body_class_counts=_count_classes(body), n_iter=n_iter, ctas=ctas,
+        threads_per_cta=256, flops_total=2.0 * H * q * kv * (qk + v) * causal,
+        bytes_accessed=kv_bytes + q_bytes, working_set=kv_bytes + q_bytes,
+        pattern="coalesced", regs=128, smem=65536, ilp=4.0,
+    )
+
+
+TEMPLATES.add("mla_attention", (mla_attention_body, mla_attention_stats))
+
+
+# ---------------------------------------------------------------------------
+# Group-limited top-k routing (two selection stages)
+# ---------------------------------------------------------------------------
+
+
+def grouped_topk_body(params):
+    """DeepSeek-V3 routing of ``rows`` tokens over ``experts``: sigmoid of
+    the router logits plus a bias correction; each of ``groups`` groups
+    scores the sum of its two best biased scores; warp-shuffle arg-max
+    rounds keep the best ``topk_group`` groups, then choose ``top_k``
+    experts inside them; the chosen unbiased scores are normalised and
+    scaled."""
+    body = [
+        I("LDG", (10,), (2,), mem={"kind": "load", "width": 16, "stride_iter": params["experts"] * 4, "base": 0x90000000, "pattern": "coalesced"}),
+        I("LDG", (11,), (3,), mem={"kind": "load", "width": 16, "stride_iter": 0, "base": 0xA0000000, "pattern": "coalesced"}),
+        I("FMUL", (12,), (10,)),
+        I("MUFU", (13,), (12,)),
+        I("FADD", (13,), (13,)),
+        I("MUFU", (14,), (13,)),
+        I("FADD", (15,), (14, 11)),
+        # stage 1: each group's two best biased scores
+        I("SHFL", (16,), (15,)),
+        I("FSETP", (), (15, 16)),
+        I("SHFL", (17,), (16,)),
+        I("FSETP", (), (16, 17)),
+        I("FADD", (18,), (16, 17)),
+        I("STS", (), (18,)),
+        I("BAR", (), ()),
+        I("LDS", (19,), ()),
+        # stage 2: the best groups, then the experts inside them
+        I("SHFL", (20,), (19,)),
+        I("FSETP", (), (19, 20)),
+        I("LOP3", (21,), (21,)),
+        I("SHFL", (22,), (15,)),
+        I("FSETP", (), (15, 22)),
+        I("FADD", (23,), (14, 23)),
+        I("MUFU", (24,), (23,)),
+        I("FMUL", (25,), (14, 24)),
+        I("STG", (), (25,), mem={"kind": "store", "width": 4, "stride_iter": params["top_k"] * 4, "base": 0xB0000000, "pattern": "coalesced"}),
+        I("STG", (), (21,), mem={"kind": "store", "width": 4, "stride_iter": params["top_k"] * 4, "base": 0xC0000000, "pattern": "coalesced"}),
+        I("IADD3", (2,), (2,)),
+        I("BRA", (), ()),
+    ]
+    # one pass of the loop per selection round
+    return body, params["topk_group"] + params["top_k"], {"warps_per_cta": 4}
+
+
+def grouped_topk_stats(params, platform):
+    rows, E, k = params["rows"], params["experts"], params["top_k"]
+    body, n_iter, _ = grouped_topk_body(params)
+    return make_stats(
+        body_class_counts=_count_classes(body), n_iter=n_iter,
+        ctas=max(1, rows // 4), threads_per_cta=128,
+        flops_total=8.0 * rows * E,
+        bytes_accessed=4.0 * (rows * E + E + 2 * rows * k),
+        working_set=4.0 * (rows * E + E + 2 * rows * k),
+        pattern="coalesced", regs=40, ilp=2.0,
+    )
+
+
+TEMPLATES.add("grouped_topk", (grouped_topk_body, grouped_topk_stats))
 
 
 def make_kernel(name, template, params, seq, seed) -> KernelInvocation:

@@ -10,6 +10,10 @@ with a 10-100x larger trace window than the scenario families:
     prefill   seq 2048, full-layer gemms   (4, 2048)   -> ~42x default graphs
     decode    4 steps against a 4096 ctx   (4, 1024)   -> ~21x default graphs
 
+Architectures that only the trace path builds (`repro.configs.TRACE_ARCHS`,
+DeepSeek-V3) take `latent_kernels`: latent attention, leading dense layers,
+group-limited routed experts beside a shared expert, and the MTP module.
+
 The window rides on `Program.trace_caps` (resolved by
 ``repro.config.resolve_trace_caps``) and is ALSO folded into
 ``fingerprint_extra``, so artifacts and cached graphs for one window can
@@ -20,7 +24,10 @@ engine (DESIGN.md §13).
 
 from __future__ import annotations
 
-from repro.tracing.programs import Program, _lm_layer_kernels
+from repro.tracing.programs import (
+    Program, _lm_layer_kernels, _mla_layer_kernels, mm_kernel,
+)
+from repro.tracing.templates import make_kernel
 
 #: default model-zoo grid: one dense-attention, one pure-SSM, one MoE arch
 MODEL_ZOO = ("llama3.2-3b", "mamba2-780m", "dbrx-132b")
@@ -50,14 +57,34 @@ def model_program(name: str) -> Program:
     if phase not in PHASES:
         raise KeyError(f"unknown phase {phase!r} (want one of {PHASES})")
 
-    from repro.config import FFN_MOE, MIXER_MAMBA2
-    from repro.configs import get_arch
+    from repro.configs import TRACE_ARCHS, get_arch
 
-    cfg = get_arch(arch_id)
     seq_len = PHASE_SEQ[phase]
     decode = phase == "decode"
     steps = DECODE_STEPS if decode else 1
     seed = 211 if decode else 199
+    if arch_id in TRACE_ARCHS:
+        ks = latent_kernels(TRACE_ARCHS.get(arch_id), seq_len, decode,
+                            steps, seed)
+    else:
+        ks = _spec_kernels(get_arch(arch_id), seq_len, decode, steps, seed)
+    for i, k in enumerate(ks):
+        k.seq = i
+
+    caps = PHASE_CAPS[phase]
+    full_name = f"model:{arch_id}:{phase}"
+    return Program(
+        full_name, ks,
+        fingerprint_extra=f"modelzoo|{arch_id}|{phase}"
+                          f"|cw{caps[0]}ci{caps[1]}",
+        trace_caps=caps,
+    )
+
+
+def _spec_kernels(cfg, seq_len, decode, steps, seed) -> list:
+    """Launch stream of a `ModelConfig`: its per-layer specs, then the
+    head, once per step."""
+    from repro.config import FFN_MOE, MIXER_MAMBA2
 
     ks = []
     s = 0
@@ -80,22 +107,60 @@ def model_program(name: str) -> Program:
         ks.append(
             make_head_kernel(cfg, seq_len, decode, s, seed))
         s += 1
-    for i, k in enumerate(ks):
-        k.seq = i
+    return ks
 
-    caps = PHASE_CAPS[phase]
-    full_name = f"model:{arch_id}:{phase}"
-    return Program(
-        full_name, ks,
-        fingerprint_extra=f"modelzoo|{arch_id}|{phase}"
-                          f"|cw{caps[0]}ci{caps[1]}",
-        trace_caps=caps,
-    )
+
+def latent_kernels(cfg, ctx, decode, steps=1, seed=211) -> list:
+    """Launch stream of ``steps`` forward steps of a `LatentMoEConfig`
+    model at batch 1 (``ctx``: the decode context, or the prefill length):
+    the token embedding; the main layers, ``first_dense`` dense and then
+    routed (`_mla_layer_kernels`); the final norm and the head;
+    then each MTP module (arXiv 2412.19437 §2.2): the embedding of the token
+    the head predicted, its norm and the norm of the main model's last
+    hidden state, ``eh_proj`` of the two concatenated, one routed layer, and
+    the shared head (a norm and the main head's product).  The MTP module
+    follows the head because its input is the head's prediction."""
+    ks: list = []
+    s = 0
+    T = 1 if decode else ctx
+    D, V = cfg.d_model, cfg.vocab_size
+
+    def add(kernel):
+        nonlocal s
+        ks.append(kernel)
+        s += 1
+
+    def layer(prefix, routed):
+        nonlocal s
+        lk, s = _mla_layer_kernels(prefix, cfg, ctx, decode, s, seed,
+                                   routed=routed)
+        ks.extend(lk)
+
+    for _step in range(steps):
+        add(make_kernel("embedding_lookup", "elementwise",
+                        {"n": T * D, "nops": 1, "iters": 2}, s, seed))
+        for i in range(cfg.num_layers):
+            layer(f"L{i}", routed=i >= cfg.first_dense)
+        add(make_kernel("final_rmsnorm", "softmax", {"rows": T, "cols": D},
+                        s, seed))
+        add(mm_kernel("lm_head_logits", V, D, decode, s, seed, m=T))
+        for depth in range(cfg.mtp_depth):
+            p = f"MTP{depth}"
+            add(make_kernel(f"{p}_embedding_lookup", "elementwise",
+                            {"n": T * D, "nops": 1, "iters": 2}, s, seed))
+            for role in ("enorm", "hnorm"):
+                add(make_kernel(f"{p}_{role}", "softmax",
+                                {"rows": T, "cols": D}, s, seed))
+            add(mm_kernel(f"{p}_eh_proj", D, 2 * D, decode, s, seed, m=T))
+            layer(p, routed=True)
+            add(make_kernel(f"{p}_shared_head_rmsnorm", "softmax",
+                            {"rows": T, "cols": D}, s, seed))
+            add(mm_kernel(f"{p}_shared_head_logits", V, D, decode, s, seed,
+                          m=T))
+    return ks
 
 
 def make_head_kernel(cfg, seq_len, decode, seq, seed):
-    from repro.tracing.templates import make_kernel
-
     if decode:
         return make_kernel("lm_head_logits", "gemv",
                            {"n": cfg.vocab_size, "m": cfg.d_model},

@@ -36,6 +36,10 @@ TEMPLATE_PARAMS = {
     "conv": {"c": 8, "hw": 32, "k": 16},
     "traversal": {"nodes": 512},   # divergent branches (mask bits vary)
     "gemv": {"n": 256, "m": 64},
+    "mla_attention": {"heads": 4, "q_len": 1, "kv_len": 256, "qk_dim": 72,
+                      "v_dim": 64, "kv_heads": 1},
+    "grouped_topk": {"rows": 4, "experts": 64, "groups": 8,
+                     "topk_group": 4, "top_k": 8},
 }
 
 _TRACE_FIELDS = ("opcode", "pc", "mask", "dest", "src",

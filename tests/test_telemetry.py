@@ -14,6 +14,7 @@ from repro.core.rgcn import RGCNConfig
 from repro.core.sampler import GCLSamplerConfig
 from repro.core.train import ContrastiveTrainer, GCLTrainConfig
 from repro.ingest.engine import IngestConfig
+from repro.sampling.engine import PlanRequest
 from repro.sampling.methods import GCLMethod
 from repro.tracing.programs import get_program
 from repro.tracing.templates import make_kernel
@@ -108,7 +109,8 @@ def test_traced_run_records_every_span_with_its_counts(runs):
     assert by["gcl.plan"][0].counts == {"programs": 1}
     assert by["fit.plan_epoch"][0].counts == {"steps": 6}
     sweep, = by["plan.sweep"]
-    assert sweep.counts == {"points": 9, "k_max": 48}
+    # 3mm's 9 points in the smallest points bucket, one program
+    assert sweep.counts == {"points": 9, "padded": 32, "k_max": 48}
     # the streaming embed encodes each distinct graph once
     packed = sum(r.counts["graphs"] for r in by["embed.pack"])
     assert packed == sum(r.counts["graphs"] for r in by["embed.encode"])
@@ -255,3 +257,21 @@ def test_buffer_is_bounded_and_counts_what_it_drops():
     for _ in range(5):
         buf.add(rec)
     assert len(buf.records) == 2 and buf.dropped == 3
+
+
+def test_plan_sweep_counts_the_padded_points_of_the_dispatch(tmp_path):
+    """``padded`` is the batch bucket times the points bucket: three
+    programs of 40, 50 and 70 points sweep as 4 rows of 128."""
+    from repro.core.clustering import sweep_cluster_stack
+
+    rng = np.random.default_rng(0)
+    xs = [rng.standard_normal((n, 4)).astype(np.float32) for n in (40, 50, 70)]
+    telemetry.clear()
+    _start_trace(tmp_path)
+    try:
+        sweep_cluster_stack(xs, k_max=4, iters=3)
+    finally:
+        jax.profiler.stop_trace()
+    sweep, = [r for r in telemetry.spans() if r.name == "plan.sweep"]
+    telemetry.clear()
+    assert sweep.counts == {"points": 160, "padded": 4 * 128, "k_max": 4}

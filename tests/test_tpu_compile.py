@@ -128,3 +128,22 @@ def test_pallas_sweep_compiles(one_chip, monkeypatch, precision):
         _spec(one_chip, (K,), jnp.int32), _spec(one_chip, (n_pad,)),
         precision=precision)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@PRECISIONS
+def test_sweep_compiles_at_the_8192_point_bucket(one_chip, precision):
+    """The jnp K-sweep the cells run, at the 8,192-point bucket that
+    DeepSeek-V3's decode trace (5,172 kernels) is the first program to
+    reach."""
+    import functools
+
+    from repro.core.clustering import _sweep_core
+
+    n_pad = 8192
+    compiled = _compile(
+        functools.partial(_sweep_core, k_max=K, iters=50, use_pallas=False,
+                          sil_block=512),
+        _spec(one_chip, (n_pad, D)), _spec(one_chip, (n_pad,)),
+        _spec(one_chip, (K,), jnp.int32), _spec(one_chip, (n_pad,)),
+        precision=precision)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
